@@ -14,10 +14,13 @@ This is the paper's deployment mode on the TPU-native runtime:
    engine) serving the identical requests,
 4. demo the streaming interface on the tensor engine.
 
-Must run in its own process (needs 8 host devices):
+A CPU example: it runs on 8 fake host devices, so it must run in its own
+process (on a TPU, ``chip_smoke.py --four-chips`` runs the pipeline on
+real chips):
     PYTHONPATH=src python examples/serve_pipeline.py
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import time

@@ -31,9 +31,9 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.compat import shard_map
+from jax import shard_map
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.core.partition import Plan
 from repro.models import transformer as tmod
@@ -117,28 +117,61 @@ def spec_from_plan(cfg: ModelConfig, plan: Plan, n_stages: int) -> PipelineSpec:
 # --------------------------------------------------------------------------- #
 
 def stack_stage_params(cfg: ModelConfig, params: PyTree, spec: PipelineSpec,
+                       mesh: Optional[Mesh] = None, stage_axis: str = "model",
                        ) -> Tuple[PyTree, jax.Array]:
     """[n_periods, ...] block params -> per-stage slabs [n_stages, l_max, ...].
 
     Returns (stage_params, valid mask [n_stages, l_max]).  Embedding / final
     norm / head stay replicated (gated by stage id at run time).
+
+    With a ``mesh``, each stage's slab is sliced out of ``params`` and put
+    straight onto the devices that own that stage along ``stage_axis``, and
+    the replicated leaves are replicated over the mesh: no device ever holds
+    more than its own layers, whatever devices ``params`` live on.  Without
+    one, the slabs are built with traceable jnp ops (``jax.eval_shape``,
+    single-device tests).
     """
     assert cfg.n_full_periods == spec.n_periods
     assert not cfg.tail, "pipeline mode requires n_layers % period == 0"
-    l_max, starts = spec.l_max, spec.starts
+    l_max, starts, sizes = spec.l_max, spec.starts, spec.periods_per_stage
 
     def restack(leaf):
         out = jnp.zeros((spec.n_stages, l_max) + leaf.shape[1:], leaf.dtype)
         for s in range(spec.n_stages):
-            n = spec.periods_per_stage[s]
-            if n:
-                out = out.at[s, :n].set(
-                    jax.lax.dynamic_slice_in_dim(leaf, starts[s], n, axis=0))
+            if sizes[s]:
+                out = out.at[s, :sizes[s]].set(jax.lax.dynamic_slice_in_dim(
+                    leaf, starts[s], sizes[s], axis=0))
         return out
 
+    def restack_on_devices(leaf):
+        shape = (spec.n_stages, l_max) + leaf.shape[1:]
+        sharding = NamedSharding(mesh, P(stage_axis))
+        shards = []
+        for dev, idx in sharding.addressable_devices_indices_map(
+                shape).items():
+            s = idx[0].start or 0           # one stage per device slice
+            part = jax.device_put(leaf[starts[s]:starts[s] + sizes[s]],
+                                  SingleDeviceSharding(dev))
+            if sizes[s] < l_max:
+                part = jnp.concatenate([part, jnp.zeros(
+                    (l_max - sizes[s],) + leaf.shape[1:], leaf.dtype,
+                    device=dev)])
+            shards.append(part[None])
+        return jax.make_array_from_single_device_arrays(shape, sharding,
+                                                        shards)
+
     stage_params = dict(params)
-    stage_params["stack"] = jax.tree.map(restack, params["stack"])
-    mask = jnp.array([[l < spec.periods_per_stage[s] for l in range(l_max)]
+    if mesh is None:
+        stage_params["stack"] = jax.tree.map(restack, params["stack"])
+    else:
+        assert mesh.shape[stage_axis] == spec.n_stages, \
+            (dict(mesh.shape), spec.n_stages)
+        replicated = NamedSharding(mesh, P())
+        stage_params = {k: jax.device_put(v, replicated)
+                        for k, v in params.items() if k != "stack"}
+        stage_params["stack"] = jax.tree.map(restack_on_devices,
+                                             params["stack"])
+    mask = jnp.array([[l < sizes[s] for l in range(l_max)]
                       for s in range(spec.n_stages)], bool)
     return stage_params, mask
 
@@ -312,6 +345,33 @@ class PipelineDecodeState:
     logits_out: jax.Array   # [M, mb, V] f32: latest last-stage logits per mb
     token_ready: jax.Array  # [M] bool: logits_out[m] was produced by the ring
     tick: jax.Array         # scalar int32
+
+
+def decode_state_shardings(cfg: ModelConfig, state: PipelineDecodeState,
+                           mesh: Mesh, paged: bool,
+                           stage_axis: str = "model",
+                           batch_axes: Tuple[str, ...] = ("data",),
+                           ) -> PipelineDecodeState:
+    """Where :func:`pipeline_decode_tick` keeps each leaf of ``state`` (a
+    state or its ``jax.eval_shape``): creating the state with these
+    shardings puts every stage's caches on its own devices from the start,
+    and the first tick compiles for the same layout as every later one."""
+    def named(spec):
+        return NamedSharding(mesh, spec)
+    if paged:
+        caches = jax.tree.map(lambda _: named(P(stage_axis)), state.caches)
+    else:
+        caches = jax.tree.map(named, _cache_pspecs(cfg, stage_axis,
+                                                   batch_axes),
+                              is_leaf=lambda x: isinstance(x, P))
+    return PipelineDecodeState(
+        caches=caches,
+        buf=named(P(stage_axis, batch_axes, None)),
+        buf_mb=named(P(stage_axis)),
+        buf_valid=named(P(stage_axis)),
+        logits_out=named(P(None, batch_axes, None)),
+        token_ready=named(P(None)),
+        tick=named(P()))
 
 
 def init_pipeline_decode_state(cfg: ModelConfig, spec: PipelineSpec,

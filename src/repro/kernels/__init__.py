@@ -1,1 +1,1 @@
-"""Pallas TPU kernels (validated on CPU via interpret mode) + jnp oracles."""
+"""Pallas TPU kernels (compiled on a TPU, interpret mode on the CPU) + jnp oracles."""

@@ -1,13 +1,20 @@
 """Single-token GQA decode attention over a (ring-buffer or paged) KV cache.
 
 The decode hot spot: one query row per sequence against a cache of up to
-524288 keys (``long_500k``).  Grid ``(batch, kv_heads, num_kv_blocks)``
-with online-softmax state in VMEM scratch; the kv axis is innermost so the
-cache streams HBM->VMEM block by block.  Every q head of a kv head's GQA
-group rides in the same grid step (query block ``[g, d]``), so each cache
+524288 keys (``long_500k``).  Grid ``(batch, num_kv_blocks)`` with
+online-softmax state in VMEM scratch; the kv axis is innermost so the cache
+streams HBM->VMEM block by block.  A kv block carries *every* kv head
+(``[bc, KH, D]``) and the kernel walks the heads inside the grid step, each
+with its whole GQA query group (query block ``[KH, R, D]``), so each cache
 block is DMA'd exactly **once** per decode step — a per-q-head grid would
 re-stream the cache ``h/kh`` times and forfeit the memory-roofline win the
 kernel exists for.
+
+Block shapes follow the TPU lowering's tiling rule (the last two block
+dimensions are multiples of ``(8, 128)`` or span the whole array): the kv
+block's last two dims are the full ``(KH, D)``, the query block's the full
+``(R, D)``, and the mask is laid out ``[B|1, n_blocks, R|1, bc]`` so its
+block is a whole ``(R|1, bc)`` tile.  The mask is int32 (nonzero = attend).
 
 Two cache layouts share the same kernel body:
 
@@ -20,10 +27,12 @@ Two cache layouts share the same kernel body:
   gather temporary exists, killing the per-step full-cache materialization
   the XLA paged path pays for.
 
-Slot validity/window masking is precomputed by the wrapper into a boolean
-``mask [1, C]`` — or ``[B, C]`` when rows decode at their own positions
-(masked length-bucketed prefill; always per-row for the paged kernel) —
-since ring buffers make validity position- not index-monotonic.
+:func:`paged_verify_attention_bhd` (speculative verify) is the same kernel
+with ``R = KQ * g`` query rows per kv head, so a one-token verify runs the
+decode kernel's exact arithmetic.
+
+Slot validity/window masking is precomputed by the wrapper from
+``key_pos`` — ring buffers make validity position- not index-monotonic.
 """
 from __future__ import annotations
 
@@ -39,16 +48,17 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref,
-                   acc_ref, *, scale: float, softcap: Optional[float]):
-    """Online-softmax decode over one (batch row, kv head)'s cache blocks.
+def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref,
+                 acc_ref, *, scale: float, softcap: Optional[float]):
+    """Online-softmax attention of one batch row over its cache blocks.
 
-    Block shapes: q/o ``[1, g, d]`` (the kv head's whole GQA query group),
-    k/v ``[1, bc, 1, d]``, mask ``[1, bc]``; scratch m/l ``[g, 1]``, acc
-    ``[g, d]`` persist across the innermost (kv-block) grid axis.
+    Block shapes: q/o ``[KH, R, d]`` (kv head ``j``'s ``R`` query rows),
+    k/v ``[bc, KH, d]``, mask ``[1|R, bc]`` int32; scratch m/l
+    ``[KH, R, 1]``, acc ``[KH, R, d]`` persist across the innermost
+    (kv-block) grid axis.
     """
-    ic = pl.program_id(2)
-    nc = pl.num_programs(2)
+    ic = pl.program_id(1)
+    nc = pl.num_programs(1)
 
     @pl.when(ic == 0)
     def _init():
@@ -56,105 +66,59 @@ def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                             # [g, d]
-    k = k_ref[0, :, 0].astype(jnp.float32)                       # [bc, d]
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    mask = mask_ref[0]                                           # [bc]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [g, bc]
-    s = s * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(mask[None, :], s, NEG_INF)
+    valid = mask_ref[...] != 0                                   # [1|R, bc]
+    for j in range(k_ref.shape[1]):                              # kv heads
+        q = q_ref[j].astype(jnp.float32)                         # [R, d]
+        k = k_ref[:, j, :].astype(jnp.float32)                   # [bc, d]
+        v = v_ref[:, j, :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # [R, bc]
+        s = s * scale
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask[None, :], jnp.exp(s - m_new), 0.0)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        m_prev = m_ref[j]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[j] = alpha * l_ref[j] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[j] = acc_ref[j] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[j] = m_new
 
     @pl.when(ic == nc - 1)
     def _finish():
         # a fully-masked row (idle paged slot: every key_pos == -1) keeps
         # l at 0; the clamp yields exact zeros instead of NaN
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, ...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _paged_decode_kernel(bt_ref, *refs, scale: float,
-                         softcap: Optional[float]):
+def _paged_kernel(bt_ref, *refs, scale: float, softcap: Optional[float]):
     """``bt_ref`` (the scalar-prefetched block table) is consumed by the kv
     BlockSpec index map, not the body — which is exactly the dense one."""
     del bt_ref
-    _decode_kernel(*refs, scale=scale, softcap=softcap)
+    _attn_kernel(*refs, scale=scale, softcap=softcap)
 
 
-def _verify_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref,
-                   acc_ref, *, scale: float, softcap: Optional[float]):
-    """Multi-token (speculative-verify) twin of :func:`_decode_kernel`.
-
-    Block shapes: q/o ``[1, kq, g, d]`` (``kq`` draft positions × the kv
-    head's GQA query group), k/v ``[1, bc, 1, d]``, mask ``[1, kq, bc]``
-    (per-q-position causality: position ``p+i`` may attend a strictly
-    larger key set than ``p``); scratch m/l ``[kq*g, 1]``, acc
-    ``[kq*g, d]``.  The q rows are flattened to one ``[kq*g, d]`` block so
-    the streaming structure — each cache block DMA'd exactly once per
-    verify step, amortized over all ``kq`` tokens — is identical to the
-    single-token kernel's.
-    """
-    ic = pl.program_id(2)
-    nc = pl.num_programs(2)
-
-    @pl.when(ic == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    kq, g, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    bc = k_ref.shape[1]
-    q = q_ref[0].astype(jnp.float32).reshape(kq * g, d)
-    k = k_ref[0, :, 0].astype(jnp.float32)                       # [bc, d]
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    mask = jnp.broadcast_to(mask_ref[0][:, None, :],
-                            (kq, g, bc)).reshape(kq * g, bc)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [kq*g, bc]
-    s = s * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(ic == nc - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, ...] = (acc_ref[...] / l).reshape(kq, g, d).astype(
-            o_ref.dtype)
+def _scratch(kh: int, r: int, d: int):
+    return [pltpu.VMEM((kh, r, 1), jnp.float32),       # m
+            pltpu.VMEM((kh, r, 1), jnp.float32),       # l
+            pltpu.VMEM((kh, r, d), jnp.float32)]       # acc
 
 
-def _paged_verify_kernel(bt_ref, *refs, scale: float,
-                         softcap: Optional[float]):
-    del bt_ref
-    _verify_kernel(*refs, scale=scale, softcap=softcap)
+def _blocked_mask(mask: jax.Array, bc: int) -> jax.Array:
+    """[M, R, C] (any truthy dtype) -> int32 [M, C // bc, R, bc]."""
+    m, r, c = mask.shape
+    return mask.astype(jnp.int32).reshape(m, r, c // bc, bc).swapaxes(1, 2)
 
 
 def decode_attention_bhd(q: jax.Array, k: jax.Array, v: jax.Array,
                          mask: jax.Array, *, softcap: Optional[float] = None,
                          block_c: int = 512, interpret: bool = False,
                          ) -> jax.Array:
-    """q [B,H,D]; k/v [B,C,KH,D]; mask [1,C] or [B,C] bool (True = attend;
+    """q [B,H,D]; k/v [B,C,KH,D]; mask [1,C] or [B,C] (nonzero = attend;
     a [B,C] mask carries per-row validity/window, e.g. per-row decode
     positions after a masked length-bucketed prefill).
 
@@ -167,35 +131,65 @@ def decode_attention_bhd(q: jax.Array, k: jax.Array, v: jax.Array,
     g = h // kh                  # GQA group: q heads sharing one kv head
     assert c % block_c == 0, (c, block_c)
     assert mask.shape[0] in (1, b), mask.shape
-    scale = 1.0 / math.sqrt(d)
-    grid = (b, kh, c // block_c)
     shared_mask = mask.shape[0] == 1
 
-    # q heads j*g..(j+1)*g-1 attend kv head j (the _sdpa grouping), so one
-    # grid step handles the whole group and each cache block is read once
-    q_spec = pl.BlockSpec((1, g, d), lambda b_, j, ic: (b_, j, 0))
-    kv_spec = pl.BlockSpec((1, block_c, 1, d),
-                           lambda b_, j, ic: (b_, ic, j, 0))
+    # q heads j*g..(j+1)*g-1 attend kv head j (the _sdpa grouping)
+    q4 = q.reshape(b, kh, g, d)
+    mask4 = _blocked_mask(mask[:, None, :], block_c)        # [1|B, nc, 1, bc]
+    q_spec = pl.BlockSpec((None, kh, g, d), lambda b_, ic: (b_, 0, 0, 0))
+    kv_spec = pl.BlockSpec((None, block_c, kh, d),
+                           lambda b_, ic: (b_, ic, 0, 0))
     mask_spec = pl.BlockSpec(
-        (1, block_c),
-        (lambda b_, j, ic: (0, ic)) if shared_mask
-        else (lambda b_, j, ic: (b_, ic)))
-    out_spec = pl.BlockSpec((1, g, d), lambda b_, j, ic: (b_, j, 0))
+        (None, None, 1, block_c),
+        (lambda b_, ic: (0, ic, 0, 0)) if shared_mask
+        else (lambda b_, ic: (b_, ic, 0, 0)))
 
-    kernel = functools.partial(_decode_kernel, scale=scale, softcap=softcap)
+    kernel = functools.partial(_attn_kernel, scale=1.0 / math.sqrt(d),
+                               softcap=softcap)
+    out = pl.pallas_call(
+        kernel,
+        grid=(b, c // block_c),
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
+        scratch_shapes=_scratch(kh, g, d),
+        interpret=interpret,
+    )(q4, k, v, mask4)
+    return out.reshape(b, h, d)
+
+
+def _paged_call(q4: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                bt: jax.Array, mask4: jax.Array, *, softcap: Optional[float],
+                interpret: bool) -> jax.Array:
+    """q4 [B, KH, R, D]; pools [NB+1, bs, KH, D]; bt [B, nbs];
+    mask4 int32 [B, nbs, 1|R, bs] -> [B, KH, R, D]."""
+    b, kh, r, d = q4.shape
+    bs = k_pool.shape[1]
+    nbs = bt.shape[1]
+    assert k_pool.shape[2] == kh, (k_pool.shape, q4.shape)
+    assert bt.shape == (b, nbs), bt.shape
+    rm = mask4.shape[2]
+    assert mask4.shape == (b, nbs, rm, bs) and rm in (1, r), mask4.shape
+
+    q_spec = pl.BlockSpec((None, kh, r, d), lambda b_, ib, bt_: (b_, 0, 0, 0))
+    kv_spec = pl.BlockSpec((None, bs, kh, d),
+                           lambda b_, ib, bt_: (bt_[b_, ib], 0, 0, 0))
+    mask_spec = pl.BlockSpec((None, None, rm, bs),
+                             lambda b_, ib, bt_: (b_, ib, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, nbs),
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
+        out_specs=q_spec,
+        scratch_shapes=_scratch(kh, r, d))
+    kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(d),
+                               softcap=softcap)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),       # m
-            pltpu.VMEM((g, 1), jnp.float32),       # l
-            pltpu.VMEM((g, d), jnp.float32),       # acc
-        ],
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
         interpret=interpret,
-    )(q, k, v, mask)
+    )(bt, q4, k_pool, v_pool, mask4)
 
 
 def paged_decode_attention_bhd(q: jax.Array, k_pool: jax.Array,
@@ -206,51 +200,24 @@ def paged_decode_attention_bhd(q: jax.Array, k_pool: jax.Array,
     """Paged GQA decode: q [B,H,D]; pools [NB+1, bs, KH, D] (last block =
     scratch); bt [B, nbs] int32 *physical* block ids (must be pre-clipped
     in-bounds — the wrapper maps unallocated ``-1`` entries to the scratch
-    block, whose keys the mask hides); mask [B, nbs*bs] bool (True = attend,
+    block, whose keys the mask hides); mask [B, nbs*bs] (nonzero = attend,
     carrying ring validity + causality + window per slot).
 
-    Returns [B, H, D].  Grid ``(batch, kv_heads, blocks_per_slot)``: the
-    block table is scalar-prefetched and indexes the kv BlockSpec directly,
-    and the kv head's whole GQA query group shares the grid step — so each
-    pool block is DMA'd exactly once and the slot's cache streams HBM->VMEM
-    once per decode step, with no gathered ``[B, C_pad, KH, D]``
-    intermediate ever materialized.
+    Returns [B, H, D].  Grid ``(batch, blocks_per_slot)``: the block table
+    is scalar-prefetched and indexes the kv BlockSpec directly, and every kv
+    head's GQA query group shares the grid step — so each pool block is
+    DMA'd exactly once and the slot's cache streams HBM->VMEM once per
+    decode step, with no gathered ``[B, C_pad, KH, D]`` intermediate ever
+    materialized.
     """
     b, h, d = q.shape
     bs, kh = k_pool.shape[1], k_pool.shape[2]
     assert h % kh == 0, (h, kh)
-    g = h // kh
-    nbs = bt.shape[1]
-    assert bt.shape == (b, nbs), bt.shape
-    assert mask.shape == (b, nbs * bs), (mask.shape, b, nbs, bs)
-    scale = 1.0 / math.sqrt(d)
-    grid = (b, kh, nbs)
-
-    q_spec = pl.BlockSpec((1, g, d), lambda b_, j, ib, bt_: (b_, j, 0))
-    kv_spec = pl.BlockSpec(
-        (1, bs, 1, d),
-        lambda b_, j, ib, bt_: (bt_[b_, ib], 0, j, 0))
-    mask_spec = pl.BlockSpec((1, bs), lambda b_, j, ib, bt_: (b_, ib))
-    out_spec = pl.BlockSpec((1, g, d), lambda b_, j, ib, bt_: (b_, j, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=out_spec,
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),       # m
-            pltpu.VMEM((g, 1), jnp.float32),       # l
-            pltpu.VMEM((g, d), jnp.float32),       # acc
-        ])
-    kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               softcap=softcap)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
-    )(bt, q, k_pool, v_pool, mask)
+    assert mask.shape == (b, bt.shape[1] * bs), (mask.shape, bt.shape, bs)
+    out = _paged_call(q.reshape(b, kh, h // kh, d), k_pool, v_pool, bt,
+                      _blocked_mask(mask[:, None, :], bs), softcap=softcap,
+                      interpret=interpret)
+    return out.reshape(b, h, d)
 
 
 def paged_verify_attention_bhd(q: jax.Array, k_pool: jax.Array,
@@ -261,52 +228,29 @@ def paged_verify_attention_bhd(q: jax.Array, k_pool: jax.Array,
     """Paged GQA *verify*: ``kq`` draft query tokens per slot in one pass.
 
     q [B, KQ, H, D]; pools [NB+1, bs, KH, D]; bt [B, nbs] pre-clipped
-    physical block ids; mask [B, KQ, nbs*bs] bool — row ``i`` carries the
+    physical block ids; mask [B, KQ, nbs*bs] — row ``i`` carries the
     causality set of position ``pos + i`` (plus ring validity/window), so
     draft token ``i`` attends every accepted key *and* the keys scattered
     for drafts ``0..i`` but not later ones.
 
-    Returns [B, KQ, H, D].  Same scalar-prefetched block-table streaming as
-    :func:`paged_decode_attention_bhd` — each pool block is DMA'd exactly
-    once per verify step, amortized over all ``kq`` tokens, which is the
-    whole speculative-decoding bandwidth win.  With ``KQ == 1`` the math
-    and accumulation order degenerate to the decode kernel's exactly.
+    Returns [B, KQ, H, D].  The ``KQ`` positions × the GQA group form the
+    ``R = KQ*g`` query rows of each kv head, so the streaming structure —
+    each pool block DMA'd exactly once per verify step, amortized over all
+    ``kq`` tokens, which is the whole speculative-decoding bandwidth win —
+    is the decode kernel's, and with ``KQ == 1`` the arithmetic is too.
     """
     b, kq, h, d = q.shape
     bs, kh = k_pool.shape[1], k_pool.shape[2]
     assert h % kh == 0, (h, kh)
     g = h // kh
-    nbs = bt.shape[1]
-    assert bt.shape == (b, nbs), bt.shape
-    assert mask.shape == (b, kq, nbs * bs), (mask.shape, b, kq, nbs, bs)
-    scale = 1.0 / math.sqrt(d)
-    grid = (b, kh, nbs)
-
-    q_spec = pl.BlockSpec((1, kq, g, d),
-                          lambda b_, j, ib, bt_: (b_, 0, j, 0))
-    kv_spec = pl.BlockSpec(
-        (1, bs, 1, d),
-        lambda b_, j, ib, bt_: (bt_[b_, ib], 0, j, 0))
-    mask_spec = pl.BlockSpec((1, kq, bs),
-                             lambda b_, j, ib, bt_: (b_, 0, ib))
-    out_spec = pl.BlockSpec((1, kq, g, d),
-                            lambda b_, j, ib, bt_: (b_, 0, j, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=out_spec,
-        scratch_shapes=[
-            pltpu.VMEM((kq * g, 1), jnp.float32),  # m
-            pltpu.VMEM((kq * g, 1), jnp.float32),  # l
-            pltpu.VMEM((kq * g, d), jnp.float32),  # acc
-        ])
-    kernel = functools.partial(_paged_verify_kernel, scale=scale,
-                               softcap=softcap)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kq, h, d), q.dtype),
-        interpret=interpret,
-    )(bt, q, k_pool, v_pool, mask)
+    c = bt.shape[1] * bs
+    assert mask.shape == (b, kq, c), (mask.shape, b, kq, bt.shape, bs)
+    # rows ordered (draft position, group member): row i*g + m
+    q4 = q.reshape(b, kq, kh, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, kh, kq * g, d)
+    rows = jnp.broadcast_to(mask[:, :, None, :], (b, kq, g, c)).reshape(
+        b, kq * g, c)
+    out = _paged_call(q4, k_pool, v_pool, bt, _blocked_mask(rows, bs),
+                      softcap=softcap, interpret=interpret)
+    return out.reshape(b, kh, kq, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, kq, h, d)

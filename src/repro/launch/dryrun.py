@@ -1,12 +1,15 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production mesh, record memory/cost analyses and collective bytes.
 
-The two lines above MUST stay the first statements in this file — jax locks
-the device count on first init, and smoke tests / benches must keep seeing
-one device, so the flag lives here and only here.
+A CPU-only compile tool: the production mesh is 512 fake host devices, so
+it never runs on (or asks for) a chip.  The lines above MUST stay the first
+statements in this file — jax locks the platform and device count on first
+init, and smoke tests / benches must keep seeing one device, so the flag
+lives here and only here.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-0.6b --shape train_4k

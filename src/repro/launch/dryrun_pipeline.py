@@ -18,8 +18,11 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun_pipeline \
         --arch starcoder2-7b --shape decode_32k [--microbatches 16] \
         [--layout even|dp] [--tag-suffix +pipeline]
+
+A CPU-only compile tool (512 fake host devices); it never runs on a chip.
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 import argparse
@@ -109,18 +112,9 @@ def run_pipeline_one(arch: str, shape_name: str, multi_pod: bool = False,
     if shape.phase == "decode":
         state_s = jax.eval_shape(functools.partial(
             pl.init_pipeline_decode_state, cfg, spec, m, mb, shape.seq_len))
-        cache_ps = pl._cache_pspecs(cfg, stage_axis, batch_axes)
-        state_sh = pl.PipelineDecodeState(
-            caches=jax.tree.map(lambda sp: NamedSharding(mesh, sp),
-                                cache_ps,
-                                is_leaf=lambda x: isinstance(x, P)),
-            buf=NamedSharding(mesh, P(stage_axis, batch_axes, None)),
-            buf_mb=NamedSharding(mesh, P(stage_axis)),
-            buf_valid=NamedSharding(mesh, P(stage_axis)),
-            logits_out=NamedSharding(mesh, P(None, batch_axes, None)),
-            token_ready=NamedSharding(mesh, P(None)),
-            tick=NamedSharding(mesh, P()),
-        )
+        state_sh = pl.decode_state_shardings(cfg, state_s, mesh, paged=False,
+                                             stage_axis=stage_axis,
+                                             batch_axes=batch_axes)
         feed_s = jax.ShapeDtypeStruct((mb,), jnp.int32)
         feed_sh = NamedSharding(mesh, P(batch_axes))
 

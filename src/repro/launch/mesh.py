@@ -11,6 +11,8 @@ from typing import Optional
 
 import jax
 
+from repro.sharding.rules import make_mesh
+
 # TPU v5e target constants — used by the roofline analysis (benchmarks/).
 PEAK_FLOPS_BF16 = 197e12      # per chip
 HBM_BW = 819e9                # bytes/s per chip
@@ -26,14 +28,14 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices for the production mesh, have {len(devices)} — "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_test_mesh(data: int = 2, model: int = 4):
     """Small mesh for CPU tests (needs host-device-count >= data*model)."""
     n = data * model
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:n])
+    return make_mesh((data, model), ("data", "model"),
+                     devices=jax.devices()[:n])
 
 
 def n_chips(mesh) -> int:

@@ -141,9 +141,16 @@ def main():
                  "--e2e-slo (steps from arrival); --priority alone only "
                  "affects --policy priority")
 
-    if args.mode == "pipeline" and not args.devices:
+    # fake host devices exist only on the CPU platform; on a chip the
+    # launcher uses the real jax.devices()
+    on_cpu = os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+    if args.devices and not on_cpu:
+        ap.error("--devices fakes XLA host devices, which only the CPU "
+                 "platform has: run with JAX_PLATFORMS=cpu, or drop --devices "
+                 "to serve on the real devices")
+    if args.mode == "pipeline" and on_cpu and not args.devices:
         args.devices = args.stages      # one fake XLA device per stage
-    if args.mode == "pipeline" and args.devices < args.stages:
+    if args.mode == "pipeline" and on_cpu and args.devices < args.stages:
         ap.error(f"--mode pipeline plans {args.stages} stages and needs one "
                  f"XLA device per stage: pass --devices >= {args.stages}, "
                  f"lower --stages, or drop --devices to default it")
@@ -157,15 +164,32 @@ def main():
 
     from repro import runtime
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import transformer as T
     from repro.serving import LLM, SamplingParams
+    from repro.sharding import make_mesh
+
+    enable_compile_cache()
+    devices = jax.devices()
+    if args.mode == "pipeline" and len(devices) < args.stages:
+        ap.error(f"--mode pipeline plans {args.stages} stages, one device "
+                 f"each, but {devices[0].platform} has {len(devices)}: pass "
+                 f"--stages {len(devices)} or fewer (on the CPU, set "
+                 f"JAX_PLATFORMS=cpu to get one host device per stage)")
 
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
     if args.kvint8:
         cfg = dataclasses.replace(cfg, kv_dtype="int8")
-    params, _ = T.init_params(cfg, jax.random.PRNGKey(args.seed))
+    key = jax.random.PRNGKey(args.seed)
+    if args.mode == "pipeline":
+        # built in place across the stage devices: no device ever holds
+        # the whole model (each stage's slab is then moved to its device)
+        params = T.init_params_on_mesh(
+            cfg, key, make_mesh((1, args.stages), ("data", "model")))
+    else:
+        params, _ = T.init_params(cfg, key)
     rng = np.random.default_rng(args.seed)
     lens = [args.prompt_len] * args.batch
     if args.varlen:
@@ -190,7 +214,7 @@ def main():
     if args.mode == "tp":
         mesh = None
         if args.devices:
-            mesh = jax.make_mesh((1, args.devices), ("data", "model"))
+            mesh = make_mesh((1, args.devices), ("data", "model"))
         backend = runtime.TensorBackend(
             cfg, params, n_slots=args.slots or args.batch,
             max_len=args.max_len, mesh=mesh, impl=args.impl, **kv_kw)

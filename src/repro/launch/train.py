@@ -40,9 +40,8 @@ def main():
     if args.devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
-    import jax
-
     from repro.configs import get_config
+    from repro.sharding import make_mesh
     from repro.training import AdamWConfig, DataConfig, TrainConfig, train
 
     cfg = get_config(args.arch)
@@ -51,9 +50,8 @@ def main():
     mesh = None
     if args.devices:
         assert args.devices % args.mesh_model == 0
-        mesh = jax.make_mesh(
-            (args.devices // args.mesh_model, args.mesh_model),
-            ("data", "model"))
+        mesh = make_mesh((args.devices // args.mesh_model, args.mesh_model),
+                         ("data", "model"))
     tcfg = TrainConfig(
         steps=args.steps, log_every=args.log_every,
         ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt_dir,

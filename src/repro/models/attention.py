@@ -158,7 +158,10 @@ def _sdpa(cfg: ModelConfig, spec: BlockSpec, q: jax.Array, k: jax.Array,
     logits = jnp.where(mask[:, None, None, :, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bngst,btnd->bsngd", probs.astype(v.dtype), v)
-    return out.reshape(b, sq, h * hd)
+    # a cache wider than the activations (float32 KV under bf16 params)
+    # must not widen the residual stream: return the queries' dtype, as the
+    # Pallas kernels do
+    return out.reshape(b, sq, h * hd).astype(q.dtype)
 
 
 def _sdpa_chunked(cfg: ModelConfig, spec: BlockSpec, q: jax.Array,

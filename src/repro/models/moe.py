@@ -19,9 +19,9 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.config import ModelConfig, MoEConfig
 from repro.models.layers import ParamBuilder
 from repro.sharding.rules import current_mesh, current_rules, logical_constraint

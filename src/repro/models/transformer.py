@@ -30,7 +30,8 @@ from repro.models.kvcache import (DEFAULT_BLOCK_SIZE, cache_logical_axes,
 from repro.models.layers import (ParamBuilder, apply_mlp, apply_norm,
                                  embed_tokens, init_embedding, init_mlp,
                                  init_norm, lm_logits, sinusoidal_embedding)
-from repro.sharding.rules import logical_constraint
+from repro.sharding.rules import (default_rules, logical_constraint,
+                                  shape_aware_sharding_tree)
 
 PyTree = Any
 
@@ -74,20 +75,23 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Tuple[PyTree, PyTree]:
     params, axes = pb.params, pb.axes
     init_norm(pb, "final_norm", cfg.d_model, cfg.norm)
 
-    # stacked full periods
+    # stacked full periods: one vmapped init per pattern entry (the same
+    # values as a per-layer loop, in one small program instead of n_layers)
     if cfg.n_full_periods > 0:
         stack_p: Dict[str, Any] = {}
         stack_a: Dict[str, Any] = {}
         for p, spec in enumerate(cfg.pattern):
-            per_period = []
-            for r in range(cfg.n_full_periods):
-                layer_idx = r * cfg.period + p
-                bp, ba = _init_block(cfg, spec, keys[1 + layer_idx], dtype)
-                per_period.append(bp)
-            stack_p[f"p{p}"] = jax.tree.map(
-                lambda *xs: jnp.stack(xs, axis=0), *per_period)
+            layer_keys = keys[1 + p + cfg.period * jnp.arange(
+                cfg.n_full_periods)]
+            block_axes = {}
+
+            def init_one(k, spec=spec):
+                bp, block_axes["tree"] = _init_block(cfg, spec, k, dtype)
+                return bp
+
+            stack_p[f"p{p}"] = jax.vmap(init_one)(layer_keys)
             stack_a[f"p{p}"] = jax.tree.map(
-                lambda t: ("layers",) + t, ba,
+                lambda t: ("layers",) + t, block_axes["tree"],
                 is_leaf=lambda t: isinstance(t, tuple))
         params["stack"] = stack_p
         axes["stack"] = stack_a
@@ -103,6 +107,25 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Tuple[PyTree, PyTree]:
         params["tail"] = tail_p
         axes["tail"] = tail_a
     return params, axes
+
+
+def init_params_on_mesh(cfg: ModelConfig, key: jax.Array, mesh) -> PyTree:
+    """:func:`init_params`, with every leaf created in place in its
+    tensor-parallel sharding over ``mesh`` (default logical-axis rules; a
+    dimension a mesh axis does not divide stays whole).  Each device only
+    ever holds its own share, so a model too large for one chip can be
+    built.  The values do not depend on the mesh (JAX's random bits are
+    partitionable): they are those of a jitted :func:`init_params`."""
+    axes = {}
+
+    def init(k):
+        params, axes["tree"] = init_params(cfg, k)
+        return params
+
+    shapes = jax.eval_shape(init, key)
+    shardings = shape_aware_sharding_tree(
+        shapes, axes["tree"], mesh, default_rules("pod" in mesh.axis_names))
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def init_caches(cfg: ModelConfig, batch: int, max_len: int,

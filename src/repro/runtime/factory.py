@@ -97,12 +97,12 @@ def from_deployment(deployment: Deployment, cluster: ClusterSpec,
                              prefix_cache=prefix_cache)
 
     if kind == "pipeline":
-        import jax
         from repro.core.pipeline import spec_from_plan
         from repro.runtime.pipeline_backend import PipelineBackend
+        from repro.sharding import make_mesh
         spec = spec_from_plan(cfg, plan, n_stages)
         if mesh is None:
-            mesh = jax.make_mesh((1, n_stages), ("data", "model"))
+            mesh = make_mesh((1, n_stages), ("data", "model"))
         return PipelineBackend(cfg, params, spec, mesh,
                                n_slots=n_slots, lanes=lanes, max_len=max_len,
                                cache_dtype=cache_dtype, impl=impl,

@@ -47,6 +47,7 @@ semantics, one lane) instead of being gathered per tick.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -108,13 +109,18 @@ class PipelineBackend(InferenceBackend):
         self._prefix_hits = 0
         self._prefix_hit_tokens = 0
 
-        with mesh:
-            self.stage_params, self.mask = PL.stack_stage_params(cfg, params,
-                                                                 spec)
-            self.state = PL.init_pipeline_decode_state(
-                cfg, spec, m, lanes, max_len, cache_dtype,
-                cache_layout="paged" if self._paged_exec else "contiguous",
-                num_blocks=self.num_blocks, block_size=block_size)
+        # every stage's layers and caches go straight to its own devices
+        self.stage_params, self.mask = PL.stack_stage_params(
+            cfg, params, spec, mesh=mesh, stage_axis=stage_axis)
+        init_state = functools.partial(
+            PL.init_pipeline_decode_state, cfg, spec, m, lanes, max_len,
+            cache_dtype,
+            cache_layout="paged" if self._paged_exec else "contiguous",
+            num_blocks=self.num_blocks, block_size=block_size)
+        state_sh = PL.decode_state_shardings(
+            cfg, jax.eval_shape(init_state), mesh, self._paged_exec,
+            stage_axis, batch_axes)
+        self.state = jax.jit(init_state, out_shardings=state_sh)()
         # pristine per-slot cache slices for admission-time resets.  Paged
         # attention entries hold no per-slot pool state — only key_pos/pos
         # rows are reset (their blocks return to the allocator host-side).

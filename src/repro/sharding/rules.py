@@ -24,7 +24,7 @@ from dataclasses import dataclass, field
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
@@ -92,6 +92,16 @@ def fsdp_rules(multi_pod: bool = False) -> AxisRules:
     base = dict(default_rules(multi_pod).rules)
     base["embed"] = ("data",)
     return AxisRules(tuple(base.items()))
+
+
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axes.  The model code places arrays with
+    sharding constraints and lets XLA propagate the rest; jax's default
+    Explicit axes refuse such constraints."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 _ctx = threading.local()

@@ -30,6 +30,7 @@ GEN = 5
 
 def run_subprocess(body: str):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # the children never ask for a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
@@ -244,11 +245,12 @@ from repro.core import pipeline as PL
 from repro.models import transformer as T
 from repro.runtime import PipelineBackend, TensorBackend
 from repro.serving import ContinuousBatcher, Request, SamplingParams
+from repro.sharding import make_mesh
 
 cfg = get_config("qwen3-0.6b").reduced(n_layers=4)
 params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
 spec = PL.even_pipeline_spec(cfg, 2)
-mesh = jax.make_mesh((1, 2), ("data", "model"))
+mesh = make_mesh((1, 2), ("data", "model"))
 rng = np.random.default_rng(0)
 prompts = rng.integers(0, cfg.vocab_size, (5, 6)).astype(np.int32)
 
@@ -358,11 +360,12 @@ from repro.core import pipeline as PL
 from repro.models import transformer as T
 from repro.runtime import PipelineBackend, TensorBackend
 from repro.serving import ContinuousBatcher, Request, SamplingParams
+from repro.sharding import make_mesh
 
 cfg = get_config("qwen3-0.6b").reduced(n_layers=4)
 params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
 spec = PL.even_pipeline_spec(cfg, 2)
-mesh = jax.make_mesh((1, 2), ("data", "model"))
+mesh = make_mesh((1, 2), ("data", "model"))
 rng = np.random.default_rng(0)
 lens = (1, 3, 5, 8, 13)
 prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32) for n in lens]

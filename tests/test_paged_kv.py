@@ -139,6 +139,31 @@ def test_generation_across_page_boundary_matches_contiguous():
     assert paged.backend.pager.free_blocks == paged.backend.pager.total_blocks
 
 
+def test_bf16_params_over_f32_pool_decode_both_impls():
+    """The serving path's default pairing — bf16 params over a float32
+    pool — decodes on both impls and they agree (GQA, g=2).  The reference
+    used to widen the residual stream to float32 and fail to trace."""
+    import jax
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    from repro.runtime import TensorBackend
+    cfg = dataclasses.replace(get_config("qwen3-0.6b").reduced(n_layers=2),
+                              dtype="bfloat16", n_kv_heads=2)
+    params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
+    prompts = np.random.default_rng(2).integers(
+        0, cfg.vocab_size, (2, 8)).astype(np.int32)
+    logits = {}
+    for impl in ("xla", "pallas"):
+        be = TensorBackend(cfg, params, n_slots=2, max_len=64,
+                           cache_layout="paged", impl=impl)
+        be.prefill([0, 1], prompts, [8, 5])
+        events = be.decode_step({0: 3, 1: 4})
+        logits[impl] = np.stack([np.asarray(e.logits) for e in events])
+    assert np.isfinite(logits["xla"]).all()
+    np.testing.assert_allclose(logits["pallas"], logits["xla"],
+                               rtol=2e-2, atol=2e-2)
+
+
 def test_pool_exhaustion_preempts_and_resumes_identically():
     """With a pool too small for all concurrent streams, serving preempts
     (recompute-on-resume) yet every request's tokens match an uninterrupted

@@ -16,6 +16,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_subprocess(body: str):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # the children never ask for a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
@@ -29,10 +30,37 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.models import transformer as T
 from repro.core import pipeline as PL
+from repro.sharding import make_mesh
 cfg = get_config("qwen3-0.6b").reduced(n_layers=6)
 params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 """
+
+
+def test_stage_params_built_on_their_own_devices():
+    """init_params_on_mesh builds each leaf in place (same values as a
+    jitted init_params); with a mesh, stack_stage_params puts each stage's
+    slab on that stage's devices only, equal to the traceable restack."""
+    run_subprocess(COMMON + """
+sharded = T.init_params_on_mesh(cfg, jax.random.PRNGKey(0), mesh)
+plain = jax.jit(lambda k: T.init_params(cfg, k)[0])(jax.random.PRNGKey(0))
+for a, b in zip(jax.tree.leaves(sharded), jax.tree.leaves(plain)):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+wq = sharded["stack"]["p0"]["mixer"]["wq"]
+assert wq.addressable_shards[0].data.shape[-1] == wq.shape[-1] // 4
+spec = PL.PipelineSpec(4, (1, 2, 2, 1))
+want, mask = PL.stack_stage_params(cfg, plain, spec)
+got, mask2 = PL.stack_stage_params(cfg, sharded, spec, mesh=mesh)
+np.testing.assert_array_equal(np.asarray(mask), np.asarray(mask2))
+for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+stage_of = {d: i for i, d in enumerate(mesh.devices[0])}
+stage_of.update({d: i for i, d in enumerate(mesh.devices[1])})
+for leaf in jax.tree.leaves(got["stack"]):
+    for shard in leaf.addressable_shards:
+        s = stage_of[shard.device]
+        assert shard.index[0] == slice(s, s + 1), (shard.index, s)
+""")
 
 
 @pytest.mark.slow
@@ -119,7 +147,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
 from repro.models import transformer as T, moe as M
-from repro.sharding.rules import use_mesh
+from repro.sharding.rules import make_mesh, use_mesh
 cfg = get_config("granite-moe-1b-a400m").reduced(n_layers=2)
 moe = cfg.pattern[0].moe
 assert moe is not None and moe.num_experts % 4 == 0
@@ -128,7 +156,7 @@ moe_params = params["stack"]["p0"]["ffn"]
 moe_params = jax.tree.map(lambda x: x[0], moe_params)
 x = jax.random.normal(jax.random.PRNGKey(1), (64, cfg.d_model))
 y_ragged, aux_r = M.moe_ragged(moe_params, moe, x)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with use_mesh(mesh):
     y_ep, aux_e = M.moe_ep(moe_params, moe, x, capacity_factor=8.0)
 np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ragged),
@@ -144,14 +172,14 @@ def test_full_model_pjit_sharded_matches_unsharded():
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.models import transformer as T
-from repro.sharding.rules import use_mesh, param_sharding_tree
+from repro.sharding.rules import make_mesh, use_mesh, param_sharding_tree
 for name in ["qwen3-0.6b", "granite-moe-1b-a400m", "gemma2-2b"]:
     cfg = get_config(name).reduced(n_layers=4)
     params, axes = T.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0,
                                 cfg.vocab_size)
     ref, _, _ = T.forward(cfg, params, tokens, mode="train")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         shardings = param_sharding_tree(axes)
         params_s = jax.device_put(params, shardings)

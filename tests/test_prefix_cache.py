@@ -31,6 +31,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_subprocess(body: str):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # the children never ask for a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
@@ -214,11 +215,12 @@ def test_pipeline_prefix_and_chunked_parity():
         from repro.models import transformer as T
         from repro.serving import LLM, SamplingParams
         from repro.runtime.pipeline_backend import PipelineBackend
+        from repro.sharding import make_mesh
 
         cfg = get_config("qwen3-0.6b").reduced(n_layers=2)
         params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
         spec = PL.even_pipeline_spec(cfg, 2)
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
 
         def mk(layout="paged", prefix=False, chunk=None):
             be = PipelineBackend(cfg, params, spec, mesh, n_slots=2,

@@ -17,6 +17,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_subprocess(body: str):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # the children never ask for a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
@@ -42,6 +43,7 @@ from repro.core.profile import Workload
 from repro.models import transformer as T
 from repro.runtime import PipelineBackend, TensorBackend
 from repro.serving import ContinuousBatcher, Request, SamplingParams
+from repro.sharding import make_mesh
 
 cfg = get_config("qwen3-0.6b").reduced(n_layers=6)
 params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
@@ -57,7 +59,7 @@ spec = PL.spec_from_plan(cfg, plan, 3)
 assert spec.n_stages >= 2
 assert len(set(spec.periods_per_stage)) > 1, spec   # genuinely uneven
 
-mesh = jax.make_mesh((1, 3), ("data", "model"))
+mesh = make_mesh((1, 3), ("data", "model"))
 rng = np.random.default_rng(0)
 N, plen, gen = 7, 6, 5
 prompts = rng.integers(0, cfg.vocab_size, (N, plen)).astype(np.int32)

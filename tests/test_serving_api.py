@@ -25,6 +25,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_subprocess(body: str):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # the children never ask for a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],

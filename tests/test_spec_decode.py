@@ -174,6 +174,7 @@ def test_pipeline_spec_parity_and_host_sampling():
     from test_backend_conformance import run_subprocess
     run_subprocess("""
     import os
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     import jax, numpy as np
     from repro.configs import get_config
@@ -182,11 +183,12 @@ def test_pipeline_spec_parity_and_host_sampling():
     from repro.runtime import PipelineBackend
     from repro.serving import ContinuousBatcher, Request, SamplingParams
     from repro.serving.spec import OracleDraft
+    from repro.sharding import make_mesh
 
     cfg = get_config("qwen3-0.6b").reduced(n_layers=4)
     params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
     spec = PL.even_pipeline_spec(cfg, 2)
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
                for n in (5, 7)]
