@@ -1,0 +1,7 @@
+"""Pipeline backend: mean host span of one tick (``decode_step``) inside
+the window, milliseconds."""
+from harness.stats import mean
+
+
+def read(run):
+    return mean([1e3 * (c.t1 - c.t0) for c in run.window_calls("tick")])
