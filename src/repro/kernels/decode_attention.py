@@ -154,15 +154,17 @@ def decode_attention_bhd(q: jax.Array, k: jax.Array, v: jax.Array,
         out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
         scratch_shapes=_scratch(kh, g, d),
         interpret=interpret,
+        name="decode_attention",
     )(q4, k, v, mask4)
     return out.reshape(b, h, d)
 
 
 def _paged_call(q4: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                 bt: jax.Array, mask4: jax.Array, *, softcap: Optional[float],
-                interpret: bool) -> jax.Array:
+                interpret: bool, name: str) -> jax.Array:
     """q4 [B, KH, R, D]; pools [NB+1, bs, KH, D]; bt [B, nbs];
-    mask4 int32 [B, nbs, 1|R, bs] -> [B, KH, R, D]."""
+    mask4 int32 [B, nbs, 1|R, bs] -> [B, KH, R, D].  ``name`` is the
+    kernel's custom call in the device trace."""
     b, kh, r, d = q4.shape
     bs = k_pool.shape[1]
     nbs = bt.shape[1]
@@ -189,6 +191,7 @@ def _paged_call(q4: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
         interpret=interpret,
+        name=name,
     )(bt, q4, k_pool, v_pool, mask4)
 
 
@@ -216,7 +219,7 @@ def paged_decode_attention_bhd(q: jax.Array, k_pool: jax.Array,
     assert mask.shape == (b, bt.shape[1] * bs), (mask.shape, bt.shape, bs)
     out = _paged_call(q.reshape(b, kh, h // kh, d), k_pool, v_pool, bt,
                       _blocked_mask(mask[:, None, :], bs), softcap=softcap,
-                      interpret=interpret)
+                      interpret=interpret, name="paged_decode_attention")
     return out.reshape(b, h, d)
 
 
@@ -251,6 +254,7 @@ def paged_verify_attention_bhd(q: jax.Array, k_pool: jax.Array,
     rows = jnp.broadcast_to(mask[:, :, None, :], (b, kq, g, c)).reshape(
         b, kq * g, c)
     out = _paged_call(q4, k_pool, v_pool, bt, _blocked_mask(rows, bs),
-                      softcap=softcap, interpret=interpret)
+                      softcap=softcap, interpret=interpret,
+                      name="paged_verify_attention")
     return out.reshape(b, kh, kq, g, d).transpose(0, 2, 1, 3, 4).reshape(
         b, kq, h, d)

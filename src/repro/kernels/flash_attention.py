@@ -123,4 +123,5 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, d), jnp.float32),     # acc
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

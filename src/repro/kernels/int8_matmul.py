@@ -62,6 +62,7 @@ def int8_matmul_pallas(x: jax.Array, w_q: jax.Array, scale: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="int8_matmul",
     )(x, w_q, scale)
 
 

@@ -54,4 +54,5 @@ def rglru_scan_pallas(log_a: jax.Array, b: jax.Array, h0: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((bb, s, r), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_r), jnp.float32)],
         interpret=interpret,
+        name="rglru_scan",
     )(a, b, h0)

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import pipeline as PL
 from repro.models import kvcache as KV
 from repro.models.attention import effective_decode_impl
@@ -128,19 +129,21 @@ class PipelineBackend(InferenceBackend):
             self._fresh_slot = jax.tree.map(lambda x: x[:, :, 0],
                                             self.state.caches)
 
-        def _tick(stage_params, mask, state, feed, feed_valid, btab):
-            return PL.pipeline_decode_tick(
-                cfg, stage_params, mask, state, feed, spec, mesh,
-                stage_axis=stage_axis, batch_axes=batch_axes, impl=impl,
-                feed_valid=feed_valid, block_tables=btab)
+        # the program's name is fixed (``jit_tick`` in the device trace)
+        if self._paged_exec:
+            def tick(stage_params, mask, state, feed, feed_valid, btab):
+                return PL.pipeline_decode_tick(
+                    cfg, stage_params, mask, state, feed, spec, mesh,
+                    stage_axis=stage_axis, batch_axes=batch_axes, impl=impl,
+                    feed_valid=feed_valid, block_tables=btab)
+        else:
+            def tick(stage_params, mask, state, feed, feed_valid):
+                return PL.pipeline_decode_tick(
+                    cfg, stage_params, mask, state, feed, spec, mesh,
+                    stage_axis=stage_axis, batch_axes=batch_axes, impl=impl,
+                    feed_valid=feed_valid)
 
-        def _tick_contig(stage_params, mask, state, feed, feed_valid):
-            return PL.pipeline_decode_tick(
-                cfg, stage_params, mask, state, feed, spec, mesh,
-                stage_axis=stage_axis, batch_axes=batch_axes, impl=impl,
-                feed_valid=feed_valid)
-
-        self._tick_fn = jax.jit(_tick if self._paged_exec else _tick_contig)
+        self._tick_fn = jax.jit(tick)
 
         if self._paged_exec:
             def _reset(state: PL.PipelineDecodeState, slot,
@@ -392,55 +395,61 @@ class PipelineBackend(InferenceBackend):
         return None                                 # stalled (no fresh token)
 
     def decode_step(self, feeds: Dict[int, int]) -> List[SlotEvent]:
-        slot = self._tick % self._m
-        feed = self._feed_for(slot, feeds)
-        valid = feed is not None
-        if valid and self._paged_exec:
-            # this tick writes position base+rounds[slot] (base = adopted
-            # shared-prefix length); grow the slot's block table first,
-            # raising BEFORE any bookkeeping so the scheduler can preempt a
-            # victim and retry the very same tick
-            pos = self._base.get(slot, 0) + self._rounds[slot]
-            need = self.pager.blocks_needed(slot, pos)
-            if need > self.pager.free_blocks:
-                raise PoolExhausted(needed=need,
-                                    free=self.pager.free_blocks)
-            if self.pager.ensure(slot, pos):
-                self._bt_dirty = True
-        if self._paged_exec and self._bt_dirty:
-            self._bt_dev = jnp.asarray(self.pager.table)
-            self._bt_dirty = False
-        if valid:
-            self._inflight[self._tick] = (slot, self._rounds[slot],
-                                          self._epoch.get(slot, 0))
-            self._rounds[slot] += 1
-        else:
-            feed = np.zeros(self.lanes, np.int32)
-        with self.mesh:
-            if self._paged_exec:
-                self.state = self._tick_fn(self.stage_params, self.mask,
-                                           self.state, jnp.asarray(feed),
-                                           jnp.asarray(valid), self._bt_dev)
+        with obs.span("repro.backend.tick", tick=self._tick):
+            slot = self._tick % self._m
+            feed = self._feed_for(slot, feeds)
+            valid = feed is not None
+            with obs.span("repro.backend.pager"):
+                if valid and self._paged_exec:
+                    # this tick writes position base+rounds[slot] (base =
+                    # adopted shared-prefix length); grow the slot's block
+                    # table first, raising BEFORE any bookkeeping so the
+                    # scheduler can preempt a victim and retry the very same
+                    # tick
+                    pos = self._base.get(slot, 0) + self._rounds[slot]
+                    need = self.pager.blocks_needed(slot, pos)
+                    if need > self.pager.free_blocks:
+                        raise PoolExhausted(needed=need,
+                                            free=self.pager.free_blocks)
+                    if self.pager.ensure(slot, pos):
+                        self._bt_dirty = True
+                if self._paged_exec and self._bt_dirty:
+                    self._bt_dev = jnp.asarray(self.pager.table)
+                    self._bt_dirty = False
+            if valid:
+                self._inflight[self._tick] = (slot, self._rounds[slot],
+                                              self._epoch.get(slot, 0))
+                self._rounds[slot] += 1
             else:
-                self.state = self._tick_fn(self.stage_params, self.mask,
-                                           self.state, jnp.asarray(feed),
-                                           feed_valid=jnp.asarray(valid))
-        events: List[SlotEvent] = []
-        done = self._inflight.pop(self._tick - (self.spec.n_stages - 1), None)
-        self._tick += 1
-        if done is None:
+                feed = np.zeros(self.lanes, np.int32)
+            with obs.span("repro.backend.dispatch"), self.mesh:
+                if self._paged_exec:
+                    self.state = self._tick_fn(self.stage_params, self.mask,
+                                               self.state, jnp.asarray(feed),
+                                               jnp.asarray(valid),
+                                               self._bt_dev)
+                else:
+                    self.state = self._tick_fn(self.stage_params, self.mask,
+                                               self.state, jnp.asarray(feed),
+                                               feed_valid=jnp.asarray(valid))
+            events: List[SlotEvent] = []
+            done = self._inflight.pop(self._tick - (self.spec.n_stages - 1),
+                                      None)
+            self._tick += 1
+            if done is None:
+                return events
+            dslot, r, epoch = done
+            if dslot in self._prompts and epoch == self._epoch.get(dslot, 0) \
+                    and self._stream_done.get(dslot, True) \
+                    and r >= len(self._prompts[dslot]) - 1:
+                with obs.span("repro.backend.fetch"):       # [lanes, V]
+                    arr = np.asarray(self.state.logits_out[dslot])
+                self._gen_ready[dslot] += 1
+                self._maybe_register_prefix(dslot)
+                events.append(SlotEvent(
+                    slot=dslot,
+                    logits=arr[0] if self.lanes == 1 else arr))
             return events
-        dslot, r, epoch = done
-        if dslot in self._prompts and epoch == self._epoch.get(dslot, 0) \
-                and self._stream_done.get(dslot, True) \
-                and r >= len(self._prompts[dslot]) - 1:
-            arr = np.asarray(self.state.logits_out[dslot])     # [lanes, V]
-            self._gen_ready[dslot] += 1
-            self._maybe_register_prefix(dslot)
-            events.append(SlotEvent(
-                slot=dslot,
-                logits=arr[0] if self.lanes == 1 else arr))
-        return events
 
     def _maybe_register_prefix(self, slot: int) -> None:
         full = self._full_tokens.pop(slot, None)

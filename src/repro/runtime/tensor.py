@@ -38,13 +38,13 @@ unpadded prefill, whichever bucket admission chose.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import kvcache as KV
 from repro.models import transformer as T
 from repro.models.attention import effective_decode_impl
@@ -64,6 +64,72 @@ def _flat_with_axes(caches: PyTree, axes: PyTree):
         axes, is_leaf=lambda t: isinstance(t, tuple))
     assert len(leaves) == len(ax_leaves), (treedef, ax_treedef)
     return leaves, ax_leaves, treedef
+
+
+def reset_stream(caches: PyTree, slot: jax.Array,
+                 start: jax.Array) -> PyTree:
+    """Wipe one slot's paged ring view for a streamed admission: mark
+    positions below ``start`` (the adopted prefix, whose blocks the
+    host just wired into the table) as valid keys, everything above as
+    empty — stale keys from the slot's previous occupant must never be
+    attended."""
+    def fix(entry, stacked):
+        if not KV.is_paged_attn_cache(entry):
+            return entry
+        e = dict(entry)
+        c_pad = entry["key_pos"].shape[-1]
+        row = jnp.where(jnp.arange(c_pad, dtype=jnp.int32) < start,
+                        jnp.arange(c_pad, dtype=jnp.int32), -1)
+        if stacked:                                  # key_pos [L, B, C]
+            e["key_pos"] = entry["key_pos"].at[:, slot].set(row[None])
+            e["pos"] = entry["pos"].at[:, slot].set(start)
+        else:
+            e["key_pos"] = entry["key_pos"].at[slot].set(row)
+            e["pos"] = entry["pos"].at[slot].set(start)
+        return e
+
+    out = dict(caches)
+    if "stack" in out:
+        out["stack"] = {k: fix(v, True) for k, v in out["stack"].items()}
+    if "tail" in out:
+        out["tail"] = {k: fix(v, False) for k, v in out["tail"].items()}
+    return out
+
+
+def rollback(caches: PyTree, new_pos: jax.Array,
+             mask: jax.Array) -> PyTree:
+    """Batched verify rollback: for every masked slot, mark positions
+    below ``new_pos[s]`` valid and everything above empty, and rewind
+    ``pos``.  Valid because the spec gate guarantees ring slot ==
+    position (no wrap), so position identity IS slot identity — a
+    rejected draft's key can be invalidated without touching any
+    surviving key."""
+    def fix(entry, stacked):
+        if not KV.is_paged_attn_cache(entry):
+            return entry
+        e = dict(entry)
+        c_pad = entry["key_pos"].shape[-1]
+        iota = jnp.arange(c_pad, dtype=jnp.int32)[None, :]
+        row = jnp.where(iota < new_pos[:, None], iota, -1)   # [B, C]
+        if stacked:
+            e["key_pos"] = jnp.where(mask[None, :, None], row[None],
+                                     entry["key_pos"])
+            e["pos"] = jnp.where(mask[None, :],
+                                 new_pos[None].astype(entry["pos"].dtype),
+                                 entry["pos"])
+        else:
+            e["key_pos"] = jnp.where(mask[:, None], row,
+                                     entry["key_pos"])
+            e["pos"] = jnp.where(mask, new_pos.astype(entry["pos"].dtype),
+                                 entry["pos"])
+        return e
+
+    out = dict(caches)
+    if "stack" in out:
+        out["stack"] = {k: fix(v, True) for k, v in out["stack"].items()}
+    if "tail" in out:
+        out["tail"] = {k: fix(v, False) for k, v in out["tail"].items()}
+    return out
 
 
 class TensorBackend(InferenceBackend):
@@ -123,34 +189,48 @@ class TensorBackend(InferenceBackend):
                 lambda x: jnp.broadcast_to(x, (n_slots,) + x.shape).copy(),
                 one)
 
-        self._prefill_fn = jax.jit(functools.partial(
-            T.forward, cfg, mode="prefill", impl=impl))
+        # each program's name is fixed (``jit_<def name>`` in the device
+        # trace's XLA Modules line), so a trace reader can tell them apart
+        def prefill(params, tokens, caches, prompt_lens):
+            return T.forward(cfg, params, tokens, mode="prefill",
+                             caches=caches, impl=impl,
+                             prompt_lens=prompt_lens)
+        self._prefill_fn = jax.jit(prefill)
 
         if self._paged_exec:
-            def _decode(params, tokens, caches, write_mask):
+            def decode_step(params, tokens, caches, write_mask):
                 return T.decode_step(cfg, params, tokens, caches, impl=impl,
                                      write_mask=write_mask)
-            self._decode_fn = jax.jit(_decode, donate_argnums=(2,))
-            self._scatter_fn = jax.jit(self._scatter_paged,
-                                       donate_argnums=(0,))
+
+            def prefill_scatter(storage, new, idx, bt_rows):
+                return self._scatter_paged(storage, new, idx, bt_rows)
+            self._decode_fn = jax.jit(decode_step, donate_argnums=(2,))
+            self._scatter_fn = jax.jit(prefill_scatter, donate_argnums=(0,))
             if self._extend_ok:
-                self._extend_fn = jax.jit(functools.partial(
-                    T.extend_step, cfg, impl=impl), donate_argnums=(2,))
-                self._reset_stream_fn = jax.jit(self._reset_stream,
+                def extend(params, tokens, caches, starts, lens):
+                    return T.extend_step(cfg, params, tokens, caches, starts,
+                                         lens, impl=impl)
+
+                def verify(params, tokens, caches, lens):
+                    return T.verify_step(cfg, params, tokens, caches, lens,
+                                         impl=impl)
+                self._extend_fn = jax.jit(extend, donate_argnums=(2,))
+                self._reset_stream_fn = jax.jit(reset_stream,
                                                 donate_argnums=(0,))
-                self._verify_fn = jax.jit(functools.partial(
-                    T.verify_step, cfg, impl=impl), donate_argnums=(2,))
-                self._rollback_fn = jax.jit(self._rollback,
-                                            donate_argnums=(0,))
+                self._verify_fn = jax.jit(verify, donate_argnums=(2,))
+                self._rollback_fn = jax.jit(rollback, donate_argnums=(0,))
         else:
-            def _decode(params, tokens, caches):
+            def decode_step(params, tokens, caches):
                 logits, new = jax.vmap(
                     lambda tok, c: T.decode_step(cfg, params, tok[None], c,
                                                  impl=impl),
                     in_axes=(0, 0))(tokens, caches)
                 return logits[:, 0], new
-            self._decode_fn = jax.jit(_decode)
-            self._scatter_fn = jax.jit(self._scatter, donate_argnums=(0,))
+
+            def prefill_scatter(storage, new, idx):
+                return self._scatter(storage, new, idx)
+            self._decode_fn = jax.jit(decode_step)
+            self._scatter_fn = jax.jit(prefill_scatter, donate_argnums=(0,))
 
         # speculative verify shares extend's preconditions: paged layout
         # with ring slot == position, so rejected drafts roll back exactly
@@ -296,70 +376,6 @@ class TensorBackend(InferenceBackend):
                 "tail", [(f"t{t}", s) for t, s in enumerate(self.cfg.tail)])
         return result
 
-    def _reset_stream(self, caches: PyTree, slot: jax.Array,
-                      start: jax.Array) -> PyTree:
-        """Wipe one slot's paged ring view for a streamed admission: mark
-        positions below ``start`` (the adopted prefix, whose blocks the
-        host just wired into the table) as valid keys, everything above as
-        empty — stale keys from the slot's previous occupant must never be
-        attended."""
-        def fix(entry, stacked):
-            if not KV.is_paged_attn_cache(entry):
-                return entry
-            e = dict(entry)
-            c_pad = entry["key_pos"].shape[-1]
-            row = jnp.where(jnp.arange(c_pad, dtype=jnp.int32) < start,
-                            jnp.arange(c_pad, dtype=jnp.int32), -1)
-            if stacked:                                  # key_pos [L, B, C]
-                e["key_pos"] = entry["key_pos"].at[:, slot].set(row[None])
-                e["pos"] = entry["pos"].at[:, slot].set(start)
-            else:
-                e["key_pos"] = entry["key_pos"].at[slot].set(row)
-                e["pos"] = entry["pos"].at[slot].set(start)
-            return e
-
-        out = dict(caches)
-        if "stack" in out:
-            out["stack"] = {k: fix(v, True) for k, v in out["stack"].items()}
-        if "tail" in out:
-            out["tail"] = {k: fix(v, False) for k, v in out["tail"].items()}
-        return out
-
-    def _rollback(self, caches: PyTree, new_pos: jax.Array,
-                  mask: jax.Array) -> PyTree:
-        """Batched verify rollback: for every masked slot, mark positions
-        below ``new_pos[s]`` valid and everything above empty, and rewind
-        ``pos``.  Valid because the spec gate guarantees ring slot ==
-        position (no wrap), so position identity IS slot identity — a
-        rejected draft's key can be invalidated without touching any
-        surviving key."""
-        def fix(entry, stacked):
-            if not KV.is_paged_attn_cache(entry):
-                return entry
-            e = dict(entry)
-            c_pad = entry["key_pos"].shape[-1]
-            iota = jnp.arange(c_pad, dtype=jnp.int32)[None, :]
-            row = jnp.where(iota < new_pos[:, None], iota, -1)   # [B, C]
-            if stacked:
-                e["key_pos"] = jnp.where(mask[None, :, None], row[None],
-                                         entry["key_pos"])
-                e["pos"] = jnp.where(mask[None, :],
-                                     new_pos[None].astype(entry["pos"].dtype),
-                                     entry["pos"])
-            else:
-                e["key_pos"] = jnp.where(mask[:, None], row,
-                                         entry["key_pos"])
-                e["pos"] = jnp.where(mask, new_pos.astype(entry["pos"].dtype),
-                                     entry["pos"])
-            return e
-
-        out = dict(caches)
-        if "stack" in out:
-            out["stack"] = {k: fix(v, True) for k, v in out["stack"].items()}
-        if "tail" in out:
-            out["tail"] = {k: fix(v, False) for k, v in out["tail"].items()}
-        return out
-
     # ------------------------------------------------------------------ #
     # speculative verify: K fed tokens per slot, one forward pass
     # ------------------------------------------------------------------ #
@@ -368,37 +384,43 @@ class TensorBackend(InferenceBackend):
             return []
         assert self._spec_ok, "backend does not advertise spec_decode"
         assert not self._pending, "verify_step before accept() of the last"
-        fed = {s: np.asarray(f, np.int32).ravel() for s, f in feeds.items()}
-        kq = max(len(f) for f in fed.values())
-        assert kq >= 1 and all(len(f) >= 1 for f in fed.values())
-        tokens = np.zeros((self.n_slots, kq), np.int32)
-        lens = np.zeros(self.n_slots, np.int32)
-        live = [s for s in sorted(fed) if self._active[s]]
-        for s in live:
-            assert int(self._pos[s]) + len(fed[s]) <= self.max_len, \
-                (s, int(self._pos[s]), len(fed[s]), self.max_len)
-            tokens[s, :len(fed[s])] = fed[s]
-            lens[s] = len(fed[s])
-        # atomic growth: blocks for ALL candidate positions up front (a
-        # rejected tail leaves its blocks allocated — they back the very
-        # next tokens anyway), raising before any state mutates
-        need = sum(
-            max(self.pager.blocks_for_len(int(self._pos[s] + lens[s]))
-                - int(self.pager.n_alloc[s]), 0) for s in live)
-        if need > self.pager.free_blocks:
-            raise PoolExhausted(needed=need, free=self.pager.free_blocks)
-        if self._grow_atomic(
-                [(s, int(self._pos[s] + lens[s]) - 1) for s in live]):
-            self._push_tables()
-        with use_mesh(self.mesh):
-            logits, self.caches = self._verify_fn(
-                self.params, jnp.asarray(tokens), self.caches,
-                jnp.asarray(lens))
-        logits = np.asarray(logits, np.float32)
-        # host _pos stays at the pre-verify position until accept() commits
-        self._pending = {s: int(lens[s]) for s in live}
-        return [SlotEvent(slot=s, logits=logits[s, :int(lens[s])])
-                for s in live]
+        with obs.span("repro.backend.verify_step", rows=len(feeds)):
+            fed = {s: np.asarray(f, np.int32).ravel()
+                   for s, f in feeds.items()}
+            kq = max(len(f) for f in fed.values())
+            assert kq >= 1 and all(len(f) >= 1 for f in fed.values())
+            tokens = np.zeros((self.n_slots, kq), np.int32)
+            lens = np.zeros(self.n_slots, np.int32)
+            live = [s for s in sorted(fed) if self._active[s]]
+            for s in live:
+                assert int(self._pos[s]) + len(fed[s]) <= self.max_len, \
+                    (s, int(self._pos[s]), len(fed[s]), self.max_len)
+                tokens[s, :len(fed[s])] = fed[s]
+                lens[s] = len(fed[s])
+            # atomic growth: blocks for ALL candidate positions up front (a
+            # rejected tail leaves its blocks allocated — they back the very
+            # next tokens anyway), raising before any state mutates
+            with obs.span("repro.backend.pager"):
+                need = sum(
+                    max(self.pager.blocks_for_len(int(self._pos[s] + lens[s]))
+                        - int(self.pager.n_alloc[s]), 0) for s in live)
+                if need > self.pager.free_blocks:
+                    raise PoolExhausted(needed=need,
+                                        free=self.pager.free_blocks)
+                if self._grow_atomic(
+                        [(s, int(self._pos[s] + lens[s]) - 1) for s in live]):
+                    self._push_tables()
+            with obs.span("repro.backend.dispatch"), use_mesh(self.mesh):
+                logits, self.caches = self._verify_fn(
+                    self.params, jnp.asarray(tokens), self.caches,
+                    jnp.asarray(lens))
+            with obs.span("repro.backend.fetch"):
+                logits = np.asarray(logits, np.float32)
+            # host _pos stays at the pre-verify position until accept()
+            # commits
+            self._pending = {s: int(lens[s]) for s in live}
+            return [SlotEvent(slot=s, logits=logits[s, :int(lens[s])])
+                    for s in live]
 
     def accept(self, counts: Dict[int, int]) -> None:
         pend, self._pending = self._pending, {}
@@ -465,42 +487,47 @@ class TensorBackend(InferenceBackend):
         sts = np.asarray(starts, np.int64)
         assert len(slots) == k and lens.shape == (k,) and sts.shape == (k,)
         assert np.all(lens >= 1) and np.all(lens <= w)
-        # atomic growth check: raise before any table mutates so the
-        # scheduler can preempt and retry the whole chunk wave
-        need = sum(
-            max(self.pager.blocks_for_len(int(st + ln))
-                - int(self.pager.n_alloc[s]), 0)
-            for s, st, ln in zip(slots, sts, lens))
-        if need > self.pager.free_blocks:
-            raise PoolExhausted(needed=need, free=self.pager.free_blocks)
-        self._grow_atomic([(s, int(st + ln) - 1)
-                           for s, st, ln in zip(slots, sts, lens)])
-        self._push_tables()
-        # extend_step works in slot space [n_slots, w]: scatter the wave's
-        # rows to their slots and make every other row a no-op (len 0 =>
-        # all writes masked to scratch, start=pos => pos unchanged), so each
-        # chunk width compiles once regardless of wave composition
-        full_chunks = np.zeros((self.n_slots, w), np.int32)
-        full_lens = np.zeros(self.n_slots, np.int32)
-        full_starts = np.asarray(self._pos, np.int32).copy()
-        for i, s in enumerate(slots):
-            full_chunks[s] = chunks[i]
-            full_lens[s] = lens[i]
-            full_starts[s] = sts[i]
-        with use_mesh(self.mesh):
-            logits, self.caches = self._extend_fn(
-                self.params, jnp.asarray(full_chunks), self.caches,
-                jnp.asarray(full_starts), jnp.asarray(full_lens))
-        last_logits = np.asarray(logits[:, -1], np.float32)
-        events = []
-        for i, s in enumerate(slots):
-            self._pos[s] = int(sts[i] + lens[i])
-            if last[i]:
-                if self._prefix_on:
-                    self._register_stream(s)
-                self._stream_tokens.pop(s, None)
-                events.append(SlotEvent(slot=s, logits=last_logits[s]))
-        return events
+        with obs.span("repro.backend.prefill_chunk", rows=k, width=w):
+            # atomic growth check: raise before any table mutates so the
+            # scheduler can preempt and retry the whole chunk wave
+            with obs.span("repro.backend.pager"):
+                need = sum(
+                    max(self.pager.blocks_for_len(int(st + ln))
+                        - int(self.pager.n_alloc[s]), 0)
+                    for s, st, ln in zip(slots, sts, lens))
+                if need > self.pager.free_blocks:
+                    raise PoolExhausted(needed=need,
+                                        free=self.pager.free_blocks)
+                self._grow_atomic([(s, int(st + ln) - 1)
+                                   for s, st, ln in zip(slots, sts, lens)])
+                self._push_tables()
+            # extend_step works in slot space [n_slots, w]: scatter the
+            # wave's rows to their slots and make every other row a no-op
+            # (len 0 => all writes masked to scratch, start=pos => pos
+            # unchanged), so each chunk width compiles once regardless of
+            # wave composition
+            full_chunks = np.zeros((self.n_slots, w), np.int32)
+            full_lens = np.zeros(self.n_slots, np.int32)
+            full_starts = np.asarray(self._pos, np.int32).copy()
+            for i, s in enumerate(slots):
+                full_chunks[s] = chunks[i]
+                full_lens[s] = lens[i]
+                full_starts[s] = sts[i]
+            with obs.span("repro.backend.dispatch"), use_mesh(self.mesh):
+                logits, self.caches = self._extend_fn(
+                    self.params, jnp.asarray(full_chunks), self.caches,
+                    jnp.asarray(full_starts), jnp.asarray(full_lens))
+            with obs.span("repro.backend.fetch"):
+                last_logits = np.asarray(logits[:, -1], np.float32)
+            events = []
+            for i, s in enumerate(slots):
+                self._pos[s] = int(sts[i] + lens[i])
+                if last[i]:
+                    if self._prefix_on:
+                        self._register_stream(s)
+                    self._stream_tokens.pop(s, None)
+                    events.append(SlotEvent(slot=s, logits=last_logits[s]))
+            return events
 
     def _register_stream(self, slot: int) -> None:
         """Index the finished stream's full token blocks for future reuse."""
@@ -572,77 +599,92 @@ class TensorBackend(InferenceBackend):
             else np.asarray(prompt_lens, np.int32)
         assert lens.shape == (k,) and np.all(lens >= 1) \
             and np.all(lens <= prompts.shape[1]), (lens, prompts.shape)
-        if self._paged_exec:
-            # atomic: on exhaustion nothing mutates and the scheduler can
-            # retry the wave after preempting.  Blocks cover each slot's
-            # TRUE length — pads are masked and never become cache keys.
-            self.pager.realloc_wave(slots, lens)
-        # pad the wave to the full slot width by repeating the first entry
-        # (duplicate scatter indices write identical values), so prefill and
-        # scatter compile once instead of per admission-wave size
-        pad = self.n_slots - k
-        prompts_p = np.concatenate(
-            [prompts, np.repeat(prompts[:1], pad, axis=0)]) if pad else prompts
-        lens_p = np.concatenate([lens, np.repeat(lens[:1], pad)]) \
-            if pad else lens
-        slots_p = list(slots) + [slots[0]] * pad
-        idx = jnp.asarray(slots_p, jnp.int32)
-        if self._paged_exec:
-            # dense scratch caches sized by the bucketed prompt length (not
-            # max_len): transient prefill workspace stays proportional to
-            # the wave, the pool holds the persistent state
-            fresh = T.init_caches(self.cfg, self.n_slots, prompts.shape[1],
-                                  self.cache_dtype)
-            bt_rows = jnp.asarray(self.pager.table[np.asarray(slots_p)])
-            with use_mesh(self.mesh):
-                logits, new_caches, _ = self._prefill_fn(
-                    self.params, jnp.asarray(prompts_p), caches=fresh,
-                    prompt_lens=jnp.asarray(lens_p))
-                self.caches = self._scatter_fn(self.caches, new_caches, idx,
-                                               bt_rows)
-            for s, n in zip(slots, lens):
-                self._pos[s] = int(n)
-                self._active[s] = True
-        else:
-            fresh = T.init_caches(self.cfg, self.n_slots, self.max_len,
-                                  self.cache_dtype)
-            with use_mesh(self.mesh):
-                logits, new_caches, _ = self._prefill_fn(
-                    self.params, jnp.asarray(prompts_p), caches=fresh,
-                    prompt_lens=jnp.asarray(lens_p))
-                self.caches = self._scatter_fn(self.caches, new_caches, idx)
-        last = np.asarray(logits[:, -1], np.float32)
-        return [SlotEvent(slot=s, logits=last[i]) for i, s in enumerate(slots)]
+        with obs.span("repro.backend.prefill", rows=k,
+                      width=prompts.shape[1]):
+            if self._paged_exec:
+                # atomic: on exhaustion nothing mutates and the scheduler can
+                # retry the wave after preempting.  Blocks cover each slot's
+                # TRUE length — pads are masked and never become cache keys.
+                with obs.span("repro.backend.pager"):
+                    self.pager.realloc_wave(slots, lens)
+            # pad the wave to the full slot width by repeating the first
+            # entry (duplicate scatter indices write identical values), so
+            # prefill and scatter compile once instead of per admission-wave
+            # size
+            pad = self.n_slots - k
+            prompts_p = np.concatenate(
+                [prompts, np.repeat(prompts[:1], pad, axis=0)]) if pad \
+                else prompts
+            lens_p = np.concatenate([lens, np.repeat(lens[:1], pad)]) \
+                if pad else lens
+            slots_p = list(slots) + [slots[0]] * pad
+            with obs.span("repro.backend.dispatch"):
+                idx = jnp.asarray(slots_p, jnp.int32)
+                if self._paged_exec:
+                    # dense scratch caches sized by the bucketed prompt
+                    # length (not max_len): transient prefill workspace stays
+                    # proportional to the wave, the pool holds the
+                    # persistent state
+                    fresh = T.init_caches(self.cfg, self.n_slots,
+                                          prompts.shape[1], self.cache_dtype)
+                    bt_rows = jnp.asarray(
+                        self.pager.table[np.asarray(slots_p)])
+                    with use_mesh(self.mesh):
+                        logits, new_caches, _ = self._prefill_fn(
+                            self.params, jnp.asarray(prompts_p), caches=fresh,
+                            prompt_lens=jnp.asarray(lens_p))
+                        self.caches = self._scatter_fn(
+                            self.caches, new_caches, idx, bt_rows)
+                    for s, n in zip(slots, lens):
+                        self._pos[s] = int(n)
+                        self._active[s] = True
+                else:
+                    fresh = T.init_caches(self.cfg, self.n_slots,
+                                          self.max_len, self.cache_dtype)
+                    with use_mesh(self.mesh):
+                        logits, new_caches, _ = self._prefill_fn(
+                            self.params, jnp.asarray(prompts_p), caches=fresh,
+                            prompt_lens=jnp.asarray(lens_p))
+                        self.caches = self._scatter_fn(self.caches,
+                                                       new_caches, idx)
+            with obs.span("repro.backend.fetch"):
+                last = np.asarray(logits[:, -1], np.float32)
+            return [SlotEvent(slot=s, logits=last[i])
+                    for i, s in enumerate(slots)]
 
     def decode_step(self, feeds: Dict[int, int]) -> List[SlotEvent]:
         if not feeds:
             return []
-        tokens = np.zeros(self.n_slots, np.int32)
-        for s, t in feeds.items():
-            tokens[s] = t
-        if self._paged_exec:
-            live = [s for s in sorted(feeds) if self._active[s]]
-            need = sum(self.pager.blocks_needed(s, int(self._pos[s]))
-                       for s in live)
-            if need > self.pager.free_blocks:     # raise BEFORE any mutation
-                raise PoolExhausted(needed=need,
-                                    free=self.pager.free_blocks)
-            if self._grow_atomic([(s, int(self._pos[s])) for s in live]):
-                self._push_tables()
-            mask = np.zeros(self.n_slots, bool)
-            mask[live] = True
-            with use_mesh(self.mesh):
-                logits, self.caches = self._decode_fn(
-                    self.params, jnp.asarray(tokens), self.caches,
-                    jnp.asarray(mask))
-            for s in live:
-                self._pos[s] += 1
-        else:
-            with use_mesh(self.mesh):
-                logits, self.caches = self._decode_fn(
-                    self.params, jnp.asarray(tokens), self.caches)
-        logits = np.asarray(logits, np.float32)
-        return [SlotEvent(slot=s, logits=logits[s]) for s in sorted(feeds)]
+        with obs.span("repro.backend.decode_step", rows=len(feeds)):
+            tokens = np.zeros(self.n_slots, np.int32)
+            for s, t in feeds.items():
+                tokens[s] = t
+            if self._paged_exec:
+                live = [s for s in sorted(feeds) if self._active[s]]
+                with obs.span("repro.backend.pager"):
+                    need = sum(self.pager.blocks_needed(s, int(self._pos[s]))
+                               for s in live)
+                    if need > self.pager.free_blocks:   # raise BEFORE
+                        raise PoolExhausted(            # any mutation
+                            needed=need, free=self.pager.free_blocks)
+                    if self._grow_atomic([(s, int(self._pos[s]))
+                                          for s in live]):
+                        self._push_tables()
+                mask = np.zeros(self.n_slots, bool)
+                mask[live] = True
+                with obs.span("repro.backend.dispatch"), use_mesh(self.mesh):
+                    logits, self.caches = self._decode_fn(
+                        self.params, jnp.asarray(tokens), self.caches,
+                        jnp.asarray(mask))
+                for s in live:
+                    self._pos[s] += 1
+            else:
+                with obs.span("repro.backend.dispatch"), use_mesh(self.mesh):
+                    logits, self.caches = self._decode_fn(
+                        self.params, jnp.asarray(tokens), self.caches)
+            with obs.span("repro.backend.fetch"):
+                logits = np.asarray(logits, np.float32)
+            return [SlotEvent(slot=s, logits=logits[s]) for s in sorted(feeds)]
 
     def free_slot(self, slot: int) -> None:
         # contiguous storage is fully overwritten on the next prefill; the

@@ -469,6 +469,7 @@ class Fleet:
             agg.starvation_avoided += s.starvation_avoided
             agg.queued += s.queued
             agg.queue_wait_steps += s.queue_wait_steps
+            agg.queue_wait_s += s.queue_wait_s
             agg.ttft_misses += s.ttft_misses
             agg.e2e_misses += s.e2e_misses
             agg.prefix_hits += s.prefix_hits

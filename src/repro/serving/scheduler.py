@@ -60,6 +60,7 @@ from typing import Callable, Deque, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.runtime.base import (BackendDead, BackendError, InferenceBackend,
                                 PoolExhausted, SlotEvent)
 from repro.serving.sched.policy import SchedPolicy, make_policy
@@ -89,6 +90,8 @@ class SchedulerStats:
     queued: int = 0                     # queue depth after the last step
     queue_wait_steps: int = 0           # cumulative steps requests spent
     #                                     queued before (re-)admission
+    queue_wait_s: float = 0.0           # the same wait in wall-clock
+    #                                     seconds (RequestTiming.queued_s)
     ttft_misses: int = 0                # first tokens past their ttft_slo
     e2e_misses: int = 0                 # finishes past their e2e_slo
     prefix_hits: int = 0                # admissions that adopted cached blocks
@@ -125,6 +128,7 @@ class SchedulerStats:
              f"utilization={self.utilization:.3f}, "
              f"queued={self.queued}, "
              f"queue_wait_steps={self.queue_wait_steps}, "
+             f"queue_wait_s={self.queue_wait_s:.3f}, "
              f"preemptions={self.preemptions}")
         if self.slo_preemptions or self.starvation_avoided:
             s += (f", slo_preemptions={self.slo_preemptions}, "
@@ -262,12 +266,13 @@ class ContinuousBatcher:
         self._admit_seq: Dict[int, int] = {}       # uid -> admission order
         self._n_admitted = 0
         # policy scheduling state: per-uid submission order (the FIFO
-        # tiebreak), cached admit keys (static per enqueue), enqueue step
-        # (queue-wait accounting), and a dirty flag so the queue is only
-        # re-sorted when it changed
+        # tiebreak), cached admit keys (static per enqueue), enqueue step and
+        # time (queue-wait accounting), and a dirty flag so the queue is
+        # only re-sorted when it changed
         self._sub_seq: Dict[int, int] = {}
         self._akey: Dict[int, Tuple] = {}
         self._enq_step: Dict[int, int] = {}
+        self._enq_s: Dict[int, float] = {}
         self._queue_dirty = False
         # streamed admission (prefix cache / chunked prefill):
         # slot -> {"tokens": unpadded prefix, "fed": tokens prefilled so far}
@@ -373,6 +378,10 @@ class ContinuousBatcher:
         self._akey[req.uid] = self.policy.admit_key(
             req, self._sub_seq[req.uid])
         self._enq_step[req.uid] = self.step_no
+        # the wall-clock wait starts at submission (RequestTiming.queue_s),
+        # or at the eviction of a preempted request
+        self._enq_s[req.uid] = time.perf_counter() if front \
+            else req.timing.submitted_s
         if front:
             self.queue.appendleft(req)
         else:
@@ -481,6 +490,7 @@ class ContinuousBatcher:
             self._admit_seq.pop(uid, None)
             self._keys.pop(uid, None)
             self._enq_step.pop(uid, None)
+            self._enq_s.pop(uid, None)
             return r
         for i, r in enumerate(self.queue):
             if r.uid == uid:
@@ -503,6 +513,10 @@ class ContinuousBatcher:
         waited = self.step_no - self._enq_step.pop(uid, self.step_no)
         r.timing.queued_steps += waited
         self.stats.queue_wait_steps += waited
+        now = time.perf_counter()
+        waited_s = now - self._enq_s.pop(uid, now)
+        r.timing.queued_s += waited_s
+        self.stats.queue_wait_s += waited_s
         self._queue_dirty = True
         return r
 
@@ -642,15 +656,19 @@ class ContinuousBatcher:
                        ) -> None:
         """Admission bookkeeping shared by every admission path: timing,
         admission order (victim tiebreak), and queue-wait attribution."""
+        if now is None:
+            now = time.perf_counter()
         req.timing.admit_step = self.step_no
-        req.timing.admitted_s = now if now is not None else \
-            time.perf_counter()
+        req.timing.admitted_s = now
         self._n_admitted += 1
         self._admit_seq[req.uid] = self._n_admitted
         waited = self.step_no - self._enq_step.pop(req.uid, self.step_no)
         self._akey.pop(req.uid, None)
         req.timing.queued_steps += waited
         self.stats.queue_wait_steps += waited
+        waited_s = now - self._enq_s.pop(req.uid, now)
+        req.timing.queued_s += waited_s
+        self.stats.queue_wait_s += waited_s
 
     def _deliver(self, req: Request, slot: int, tok: int,
                  out: List[TokenEvent], *,
@@ -705,11 +723,12 @@ class ContinuousBatcher:
         return reason
 
     def _handle(self, events: List[SlotEvent], out: List[TokenEvent]):
-        for ev in events:
-            req = self._slot_req.get(ev.slot)
-            if req is None:
-                continue
-            self._deliver(req, ev.slot, self._sample(req, ev), out)
+        with obs.span("repro.sched.sample", rows=len(events)):
+            for ev in events:
+                req = self._slot_req.get(ev.slot)
+                if req is None:
+                    continue
+                self._deliver(req, ev.slot, self._sample(req, ev), out)
 
     # ------------------------------------------------------------------ #
     # speculative decoding (draft -> verify -> accept)
@@ -760,33 +779,34 @@ class ContinuousBatcher:
         events = self.backend.verify_step(feeds)
         counts: Dict[int, int] = {}
         finished_slots: List[int] = []
-        for ev in events:
-            req = self._slot_req.get(ev.slot)
-            if req is None:                     # defensive: still accept
-                counts[ev.slot] = 1
-                continue
-            g = self._verify_outputs(req, ev)
-            fed = feeds.get(ev.slot)
-            if fed is None:
-                emit = g[:1]    # pipeline prompt-completion: first token
-            else:
-                assert len(g) == len(fed), (len(g), len(fed))
-                emit = [g[0]]
-                for i in range(1, len(fed)):
-                    if int(fed[i]) == emit[-1]:
-                        emit.append(g[i])
-                    else:
+        with obs.span("repro.sched.sample", rows=len(events)):
+            for ev in events:
+                req = self._slot_req.get(ev.slot)
+                if req is None:                     # defensive: still accept
+                    counts[ev.slot] = 1
+                    continue
+                g = self._verify_outputs(req, ev)
+                fed = feeds.get(ev.slot)
+                if fed is None:
+                    emit = g[:1]    # pipeline prompt-completion: first token
+                else:
+                    assert len(g) == len(fed), (len(g), len(fed))
+                    emit = [g[0]]
+                    for i in range(1, len(fed)):
+                        if int(fed[i]) == emit[-1]:
+                            emit.append(g[i])
+                        else:
+                            break
+                    self.stats.spec_drafted += len(fed) - 1
+                    self.stats.spec_accepted += len(emit) - 1
+                n_emitted = 0
+                for tok in emit:
+                    n_emitted += 1
+                    if self._deliver(req, ev.slot, tok, out,
+                                     release_slot=False) is not None:
+                        finished_slots.append(ev.slot)
                         break
-                self.stats.spec_drafted += len(fed) - 1
-                self.stats.spec_accepted += len(emit) - 1
-            n_emitted = 0
-            for tok in emit:
-                n_emitted += 1
-                if self._deliver(req, ev.slot, tok, out,
-                                 release_slot=False) is not None:
-                    finished_slots.append(ev.slot)
-                    break
-            counts[ev.slot] = n_emitted
+                counts[ev.slot] = n_emitted
         self.backend.accept(counts)
         for slot in finished_slots:
             self.backend.free_slot(slot)
@@ -845,6 +865,140 @@ class ContinuousBatcher:
                 self.stats.prefill_shapes.get(width, 0) + 1
             self._handle(events, out)
 
+    def _admit(self, out: List[TokenEvent]) -> None:
+        """Admission: fill free slots without draining the running batch;
+        one prefill call per length bucket keeps XLA shapes bounded."""
+        info = self.backend.info
+        budget = self._admit_block_budget()
+        # streamed admission whenever there is something to gain from it:
+        # a prefix cache to hit, or chunking requested on a backend that
+        # can extend a partially-prefilled slot
+        use_stream = info.prefix_caching or \
+            (self.prefill_chunk is not None and info.supports_extend)
+        while self.queue and self._free:
+            with obs.span("repro.sched.admit") as admit:
+                head = self.queue[0]
+                if use_stream:
+                    # singleton admission: the backend adopts any cached prefix
+                    # blocks now (copy-on-write incref, no compute) and the
+                    # chunk pump below prefills the remaining suffix.  Resumed
+                    # requests route through the same path — their recompute
+                    # prefix can itself hit the cache.
+                    prefix = self._resume.get(head.uid)
+                    tokens = np.asarray(
+                        head.prompt if prefix is None else prefix, np.int32)
+                    need = info.blocks_for_len(len(tokens))
+                    if budget is not None and need > budget:
+                        break
+                    req = self.queue.popleft()
+                    slot = self._free.popleft()
+                    admit.set_metadata(rows=1, **obs.uids([req.uid]))
+                    try:
+                        start = self.backend.start_stream(slot, tokens)
+                    except BackendError as e:
+                        # nothing mutated (typed-failure contract): restore the
+                        # admission state and either wait out the pool or
+                        # retry/escalate the failure
+                        self._free.appendleft(slot)
+                        self.queue.appendleft(req)
+                        self._queue_dirty = True
+                        if isinstance(e, PoolExhausted):
+                            break
+                        if not self._note_failure(e):
+                            raise
+                        break
+                    if prefix is not None:
+                        del self._resume[req.uid]
+                        self.stats.resumes += 1
+                    self._slot_req[slot] = req
+                    self._mark_admitted(req)
+                    self._chunking[slot] = {"tokens": tokens, "fed": start}
+                    self.stats.prefills += 1
+                    if start:
+                        self.stats.prefix_hits += 1
+                        self.stats.prefix_hit_tokens += start
+                    if budget is not None:
+                        budget = max(budget - need, 0)
+                    continue
+                if head.uid in self._resume:
+                    # resumed requests re-prefill their prefix (prompt +
+                    # generated tokens) as a singleton wave, bucketed through
+                    # the same power-of-two shapes as fresh admissions —
+                    # masked prefill makes the padding invisible, so resumes
+                    # no longer compile one fresh XLA prefill shape per exact
+                    # length
+                    prefix = self._resume[head.uid]
+                    plen = len(prefix)
+                    blen = self._bucket(plen)
+                    need = info.blocks_for_len(plen)
+                    if budget is not None and need > budget:
+                        break
+                    req = self.queue.popleft()
+                    wave, lens = [req], [plen]
+                    padded = np.full((1, blen), self.pad_id, np.int32)
+                    padded[0, blen - plen:] = prefix
+                    resumed = True
+                else:
+                    resumed = False
+                    blen = self._bucket(len(head.prompt))
+                    # cap the wave by the bucket's worst-case block demand
+                    # (true-length demand, summed below, can only be smaller)
+                    need_each = info.blocks_for_len(blen)
+                    cap = len(self._free)
+                    if budget is not None:
+                        if need_each > budget:
+                            break
+                        if need_each:
+                            cap = min(cap, budget // need_each)
+                    blen, wave = self._next_wave(cap)
+                    if not wave:                    # defensive: never expected
+                        break
+                    lens = [len(r.prompt) for r in wave]
+                    need = sum(info.blocks_for_len(n) for n in lens)
+                    padded = np.full((len(wave), blen), self.pad_id, np.int32)
+                    for i, req in enumerate(wave):
+                        padded[i, blen - len(req.prompt):] = req.prompt
+                admit.set_metadata(bucket=blen, rows=len(wave),
+                                   **obs.uids(r.uid for r in wave))
+                slots = [self._free.popleft() for _ in wave]
+                # the wave is admitted as it is dispatched, as on the
+                # streamed path: its queue wait ends before its prefill runs
+                now = time.perf_counter()
+                try:
+                    events = self.backend.prefill(slots, padded,
+                                                  prompt_lens=lens)
+                except BackendError as e:
+                    # the lazy-allocating pipeline can reach PoolExhausted
+                    # here despite the budget gate, and any backend may fail
+                    # transiently; either way nothing mutated — put
+                    # everything back (a resumed request keeps its _resume
+                    # prefix — it is only dropped on success).  Pool pressure
+                    # waits for decode to drain; typed failures retry with
+                    # backoff or escalate.
+                    for s in reversed(slots):
+                        self._free.appendleft(s)
+                    for r in reversed(wave):
+                        self.queue.appendleft(r)
+                    self._queue_dirty = True
+                    if isinstance(e, PoolExhausted):
+                        break
+                    if not self._note_failure(e):
+                        raise
+                    break
+                if resumed:
+                    del self._resume[wave[0].uid]
+                for slot, req in zip(slots, wave):
+                    self._slot_req[slot] = req
+                    self._mark_admitted(req, now)
+                self.stats.prefills += 1
+                if resumed:
+                    self.stats.resumes += 1
+                self.stats.prefill_shapes[blen] = \
+                    self.stats.prefill_shapes.get(blen, 0) + 1
+                if budget is not None:
+                    budget = max(budget - need, 0)
+                self._handle(events, out)
+
     def step(self) -> List[TokenEvent]:
         """Advance one scheduler quantum: release staged arrivals, admit
         bucketed waves into free slots, run one backend decode quantum.
@@ -871,166 +1025,44 @@ class ContinuousBatcher:
             self.stats.queued = len(self.queue)
             self.step_no += 1
             return out
-        # policy order first: the rest of admission just pulls queue[0]
-        self._sort_queue()
-        # SLO preemption: a preemptive policy may evict one victim per step
-        # for a *blocked* urgent head — blocked (no free slot / no block
-        # budget for it) is the saturation signal; an idle system admits
-        # normally
-        if self.queue and self.policy.preemptive and self._slo_preempt():
-            self._sort_queue()          # the victim re-queued at the front
-        # admission: fill free slots without draining the running batch;
-        # one prefill call per length bucket keeps XLA shapes bounded
-        info = self.backend.info
-        budget = self._admit_block_budget()
-        # streamed admission whenever there is something to gain from it:
-        # a prefix cache to hit, or chunking requested on a backend that
-        # can extend a partially-prefilled slot
-        use_stream = info.prefix_caching or \
-            (self.prefill_chunk is not None and info.supports_extend)
-        while self.queue and self._free:
-            head = self.queue[0]
-            if use_stream:
-                # singleton admission: the backend adopts any cached prefix
-                # blocks now (copy-on-write incref, no compute) and the
-                # chunk pump below prefills the remaining suffix.  Resumed
-                # requests route through the same path — their recompute
-                # prefix can itself hit the cache.
-                prefix = self._resume.get(head.uid)
-                tokens = np.asarray(
-                    head.prompt if prefix is None else prefix, np.int32)
-                need = info.blocks_for_len(len(tokens))
-                if budget is not None and need > budget:
-                    break
-                req = self.queue.popleft()
-                slot = self._free.popleft()
-                try:
-                    start = self.backend.start_stream(slot, tokens)
-                except BackendError as e:
-                    # nothing mutated (typed-failure contract): restore the
-                    # admission state and either wait out the pool or
-                    # retry/escalate the failure
-                    self._free.appendleft(slot)
-                    self.queue.appendleft(req)
-                    self._queue_dirty = True
-                    if isinstance(e, PoolExhausted):
+        with obs.span("repro.sched.step", step=self.step_no):
+            # policy order first: the rest of admission just pulls queue[0]
+            self._sort_queue()
+            # SLO preemption: a preemptive policy may evict one victim per step
+            # for a *blocked* urgent head — blocked (no free slot / no block
+            # budget for it) is the saturation signal; an idle system admits
+            # normally
+            if self.queue and self.policy.preemptive and self._slo_preempt():
+                self._sort_queue()          # the victim re-queued at the front
+            self._admit(out)
+            if self._chunking:
+                self._pump_chunks(out)
+            if self._slot_req:
+                self.stats.decode_steps += 1
+                self.stats.slot_total_steps += self.backend.n_slots
+                self.stats.slot_busy_steps += len(self._slot_req)
+                while True:
+                    try:
+                        if self._spec_on:
+                            # verify_step delivers internally (variable tokens
+                            # per slot per quantum)
+                            self._verify_quantum(out)
+                        else:
+                            self._handle(self.backend.decode_step(self._feeds),
+                                         out)
+                        self._consec_failures = 0   # a served quantum resets
+                        #                             the transient streak
                         break
-                    if not self._note_failure(e):
-                        raise
-                    break
-                if prefix is not None:
-                    del self._resume[req.uid]
-                    self.stats.resumes += 1
-                self._slot_req[slot] = req
-                self._mark_admitted(req)
-                self._chunking[slot] = {"tokens": tokens, "fed": start}
-                self.stats.prefills += 1
-                if start:
-                    self.stats.prefix_hits += 1
-                    self.stats.prefix_hit_tokens += start
-                if budget is not None:
-                    budget = max(budget - need, 0)
-                continue
-            if head.uid in self._resume:
-                # resumed requests re-prefill their prefix (prompt +
-                # generated tokens) as a singleton wave, bucketed through
-                # the same power-of-two shapes as fresh admissions — masked
-                # prefill makes the padding invisible, so resumes no longer
-                # compile one fresh XLA prefill shape per exact length
-                prefix = self._resume[head.uid]
-                plen = len(prefix)
-                blen = self._bucket(plen)
-                need = info.blocks_for_len(plen)
-                if budget is not None and need > budget:
-                    break
-                req = self.queue.popleft()
-                wave, lens = [req], [plen]
-                padded = np.full((1, blen), self.pad_id, np.int32)
-                padded[0, blen - plen:] = prefix
-                resumed = True
-            else:
-                resumed = False
-                blen = self._bucket(len(head.prompt))
-                # cap the wave by the bucket's worst-case block demand
-                # (true-length demand, summed below, can only be smaller)
-                need_each = info.blocks_for_len(blen)
-                cap = len(self._free)
-                if budget is not None:
-                    if need_each > budget:
+                    except PoolExhausted:
+                        if not self._preempt_victim():
+                            raise   # a lone request outgrowing the pool is a
+                                    # sizing bug submit() should have rejected
+                    except BackendError as e:
+                        # typed failure, nothing mutated: the same feeds retry
+                        # after backoff, or the failure escalates to the fleet
+                        if not self._note_failure(e):
+                            raise
                         break
-                    if need_each:
-                        cap = min(cap, budget // need_each)
-                blen, wave = self._next_wave(cap)
-                if not wave:                    # defensive: never expected
-                    break
-                lens = [len(r.prompt) for r in wave]
-                need = sum(info.blocks_for_len(n) for n in lens)
-                padded = np.full((len(wave), blen), self.pad_id, np.int32)
-                for i, req in enumerate(wave):
-                    padded[i, blen - len(req.prompt):] = req.prompt
-            slots = [self._free.popleft() for _ in wave]
-            try:
-                events = self.backend.prefill(slots, padded,
-                                              prompt_lens=lens)
-            except BackendError as e:
-                # the lazy-allocating pipeline can reach PoolExhausted here
-                # despite the budget gate, and any backend may fail
-                # transiently; either way nothing mutated — put everything
-                # back (a resumed request keeps its _resume prefix — it is
-                # only dropped on success).  Pool pressure waits for decode
-                # to drain; typed failures retry with backoff or escalate.
-                for s in reversed(slots):
-                    self._free.appendleft(s)
-                for r in reversed(wave):
-                    self.queue.appendleft(r)
-                self._queue_dirty = True
-                if isinstance(e, PoolExhausted):
-                    break
-                if not self._note_failure(e):
-                    raise
-                break
-            if resumed:
-                del self._resume[wave[0].uid]
-            now = time.perf_counter()
-            for slot, req in zip(slots, wave):
-                self._slot_req[slot] = req
-                self._mark_admitted(req, now)
-            self.stats.prefills += 1
-            if resumed:
-                self.stats.resumes += 1
-            self.stats.prefill_shapes[blen] = \
-                self.stats.prefill_shapes.get(blen, 0) + 1
-            if budget is not None:
-                budget = max(budget - need, 0)
-            self._handle(events, out)
-        if self._chunking:
-            self._pump_chunks(out)
-        if self._slot_req:
-            self.stats.decode_steps += 1
-            self.stats.slot_total_steps += self.backend.n_slots
-            self.stats.slot_busy_steps += len(self._slot_req)
-            while True:
-                try:
-                    if self._spec_on:
-                        # verify_step delivers internally (variable tokens
-                        # per slot per quantum)
-                        self._verify_quantum(out)
-                    else:
-                        self._handle(self.backend.decode_step(self._feeds),
-                                     out)
-                    self._consec_failures = 0   # a served quantum resets
-                    #                             the transient streak
-                    break
-                except PoolExhausted:
-                    if not self._preempt_victim():
-                        raise   # a lone request outgrowing the pool is a
-                                # sizing bug submit() should have rejected
-                except BackendError as e:
-                    # typed failure, nothing mutated: the same feeds retry
-                    # after backoff, or the failure escalates to the fleet
-                    if not self._note_failure(e):
-                        raise
-                    break
         self.stats.queued = len(self.queue)
         self.step_no += 1
         return out

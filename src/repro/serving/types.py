@@ -98,6 +98,9 @@ class RequestTiming:
     #: across re-queues after preemption): attributes latency to queueing
     #: vs execution
     queued_steps: int = 0
+    #: the same wait in wall-clock seconds: ``queue_s`` for the first
+    #: admission, plus eviction → re-admission for each resume
+    queued_s: float = 0.0
 
     @property
     def queue_s(self) -> Optional[float]:

@@ -93,3 +93,24 @@ def test_flash_prefill_compiles(shape):
     _assert_kernel(ops.flash_attention.lower(
         shape((2, s, H, D), jnp.bfloat16), shape((2, s, KH, D), jnp.bfloat16),
         shape((2, s, KH, D), jnp.bfloat16), interpret=False))
+
+
+def test_paged_decode_kernel_keeps_its_name(shape):
+    """The benchmark finds the paged decode kernel in the device trace by
+    its custom call, ``paged_decode_attention.N``.  Inside a program of
+    another name the call keeps that name through the kernel's own."""
+    from repro.kernels.decode_attention import paged_decode_attention_bhd
+    pool = shape((NB + 1, BS, KH, D), jnp.float32)
+
+    def decode_step(q, k_pool, v_pool, bt, mask):
+        return paged_decode_attention_bhd(q, k_pool, v_pool, bt, mask,
+                                          interpret=False)
+    text = jax.jit(decode_step).lower(
+        shape((SLOTS, H, D), jnp.bfloat16), pool, pool,
+        shape((SLOTS, NBS), jnp.int32),
+        shape((SLOTS, MAX_LEN), jnp.int32)).compile().as_text()
+    calls = [line.split(" = ", 1)[0].strip().lstrip("%")
+             for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert calls
+    assert all(c.startswith("paged_decode_attention") for c in calls), calls
