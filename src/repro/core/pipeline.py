@@ -38,8 +38,9 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from repro.core.partition import Plan
 from repro.models import transformer as tmod
 from repro.models.config import ModelConfig
-from repro.models.kvcache import (DEFAULT_BLOCK_SIZE, cache_logical_axes,
-                                  init_block_cache, init_paged_block_cache)
+from repro.models.kvcache import (DEFAULT_BLOCK_SIZE, POOL_KEYS,
+                                  cache_logical_axes, init_block_cache,
+                                  init_paged_block_cache)
 from repro.models.layers import apply_norm, embed_tokens, lm_logits
 
 PyTree = Any
@@ -515,9 +516,7 @@ def pipeline_decode_tick(cfg: ModelConfig, stage_params: PyTree,
                     # key_pos/pos slices.  Writes are gated inside the
                     # paged attention (scratch redirect + frozen pos), so
                     # a dead tick cannot touch another slot's blocks.
-                    my = {k: lc[k] for k in
-                          ("k_pool", "v_pool", "k_scale_pool",
-                           "v_scale_pool") if k in lc}
+                    my = {k: lc[k] for k in POOL_KEYS if k in lc}
                     my["bt"] = bt_slot
                     my["key_pos"] = jax.lax.dynamic_index_in_dim(
                         lc["key_pos"], mb_idx, 0, keepdims=False)

@@ -23,6 +23,8 @@ Two cache layouts share the same kernel body:
   ``[NB+1, bs, KH, D]`` read *through the slot's block table*: the table is
   scalar-prefetched and drives the kv ``BlockSpec`` index map, so block
   ``ib`` of slot ``b`` streams pool block ``bt[b, ib]`` HBM->VMEM directly.
+  A pool stacked over layers ``[L, NB+1, bs, KH, D]`` is read in place at a
+  scalar-prefetched layer index, so a decode layer scan never slices it.
   This is the vLLM-style fused indirection — no ``[B, C_pad, KH, D]``
   gather temporary exists, killing the per-step full-cache materialization
   the XLA paged path pays for.
@@ -95,10 +97,12 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref,
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _paged_kernel(bt_ref, *refs, scale: float, softcap: Optional[float]):
-    """``bt_ref`` (the scalar-prefetched block table) is consumed by the kv
-    BlockSpec index map, not the body — which is exactly the dense one."""
-    del bt_ref
+def _paged_kernel(bt_ref, layer_ref, *refs, scale: float,
+                  softcap: Optional[float]):
+    """``bt_ref`` and ``layer_ref`` (the scalar-prefetched block table and
+    pool layer) are consumed by the kv BlockSpec index map, not the body —
+    which is exactly the dense one."""
+    del bt_ref, layer_ref
     _attn_kernel(*refs, scale=scale, softcap=softcap)
 
 
@@ -159,27 +163,46 @@ def decode_attention_bhd(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(b, h, d)
 
 
+def _layered(k_pool: jax.Array, v_pool: jax.Array,
+             layer: Optional[jax.Array]):
+    """Pools with a leading layer axis ``[L, NB+1, bs, KH, D]`` and the
+    layer to read as int32 ``[1]``.  An unstacked ``[NB+1, bs, KH, D]``
+    pool (``layer`` None) becomes ``pool[None]`` at layer 0 — a bitcast,
+    not a copy."""
+    if layer is None:
+        assert k_pool.ndim == 4, k_pool.shape
+        return k_pool[None], v_pool[None], jnp.zeros((1,), jnp.int32)
+    assert k_pool.ndim == 5, k_pool.shape
+    return k_pool, v_pool, jnp.reshape(layer, (1,)).astype(jnp.int32)
+
+
 def _paged_call(q4: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                bt: jax.Array, mask4: jax.Array, *, softcap: Optional[float],
-                interpret: bool, name: str) -> jax.Array:
-    """q4 [B, KH, R, D]; pools [NB+1, bs, KH, D]; bt [B, nbs];
-    mask4 int32 [B, nbs, 1|R, bs] -> [B, KH, R, D].  ``name`` is the
-    kernel's custom call in the device trace."""
+                bt: jax.Array, layer: jax.Array, mask4: jax.Array, *,
+                softcap: Optional[float], interpret: bool,
+                name: str) -> jax.Array:
+    """q4 [B, KH, R, D]; pools [L, NB+1, bs, KH, D]; bt [B, nbs]; layer
+    int32 [1]; mask4 int32 [B, nbs, 1|R, bs] -> [B, KH, R, D].  The pools
+    are read in place at ``layer`` through the kv index map, so a stacked
+    pool is never sliced.  ``name`` is the kernel's custom call in the
+    device trace."""
     b, kh, r, d = q4.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     nbs = bt.shape[1]
-    assert k_pool.shape[2] == kh, (k_pool.shape, q4.shape)
+    assert k_pool.shape[3] == kh, (k_pool.shape, q4.shape)
     assert bt.shape == (b, nbs), bt.shape
+    assert layer.shape == (1,), layer.shape
     rm = mask4.shape[2]
     assert mask4.shape == (b, nbs, rm, bs) and rm in (1, r), mask4.shape
 
-    q_spec = pl.BlockSpec((None, kh, r, d), lambda b_, ib, bt_: (b_, 0, 0, 0))
-    kv_spec = pl.BlockSpec((None, bs, kh, d),
-                           lambda b_, ib, bt_: (bt_[b_, ib], 0, 0, 0))
+    q_spec = pl.BlockSpec((None, kh, r, d),
+                          lambda b_, ib, bt_, l_: (b_, 0, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, bs, kh, d),
+        lambda b_, ib, bt_, l_: (l_[0], bt_[b_, ib], 0, 0, 0))
     mask_spec = pl.BlockSpec((None, None, rm, bs),
-                             lambda b_, ib, bt_: (b_, ib, 0, 0))
+                             lambda b_, ib, bt_, l_: (b_, ib, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, nbs),
         in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
         out_specs=q_spec,
@@ -192,19 +215,22 @@ def _paged_call(q4: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
         interpret=interpret,
         name=name,
-    )(bt, q4, k_pool, v_pool, mask4)
+    )(bt, layer, q4, k_pool, v_pool, mask4)
 
 
 def paged_decode_attention_bhd(q: jax.Array, k_pool: jax.Array,
                                v_pool: jax.Array, bt: jax.Array,
                                mask: jax.Array, *,
+                               layer: Optional[jax.Array] = None,
                                softcap: Optional[float] = None,
                                interpret: bool = False) -> jax.Array:
     """Paged GQA decode: q [B,H,D]; pools [NB+1, bs, KH, D] (last block =
-    scratch); bt [B, nbs] int32 *physical* block ids (must be pre-clipped
-    in-bounds — the wrapper maps unallocated ``-1`` entries to the scratch
-    block, whose keys the mask hides); mask [B, nbs*bs] (nonzero = attend,
-    carrying ring validity + causality + window per slot).
+    scratch), or stacked ``[L, NB+1, bs, KH, D]`` and read in place at
+    ``layer`` (scalar int32); bt [B, nbs] int32 *physical* block ids (must
+    be pre-clipped in-bounds — the wrapper maps unallocated ``-1`` entries
+    to the scratch block, whose keys the mask hides); mask [B, nbs*bs]
+    (nonzero = attend, carrying ring validity + causality + window per
+    slot).
 
     Returns [B, H, D].  Grid ``(batch, blocks_per_slot)``: the block table
     is scalar-prefetched and indexes the kv BlockSpec directly, and every kv
@@ -214,23 +240,27 @@ def paged_decode_attention_bhd(q: jax.Array, k_pool: jax.Array,
     materialized.
     """
     b, h, d = q.shape
-    bs, kh = k_pool.shape[1], k_pool.shape[2]
+    k_pool, v_pool, layer = _layered(k_pool, v_pool, layer)
+    bs, kh = k_pool.shape[2], k_pool.shape[3]
     assert h % kh == 0, (h, kh)
     assert mask.shape == (b, bt.shape[1] * bs), (mask.shape, bt.shape, bs)
     out = _paged_call(q.reshape(b, kh, h // kh, d), k_pool, v_pool, bt,
-                      _blocked_mask(mask[:, None, :], bs), softcap=softcap,
-                      interpret=interpret, name="paged_decode_attention")
+                      layer, _blocked_mask(mask[:, None, :], bs),
+                      softcap=softcap, interpret=interpret,
+                      name="paged_decode_attention")
     return out.reshape(b, h, d)
 
 
 def paged_verify_attention_bhd(q: jax.Array, k_pool: jax.Array,
                                v_pool: jax.Array, bt: jax.Array,
                                mask: jax.Array, *,
+                               layer: Optional[jax.Array] = None,
                                softcap: Optional[float] = None,
                                interpret: bool = False) -> jax.Array:
     """Paged GQA *verify*: ``kq`` draft query tokens per slot in one pass.
 
-    q [B, KQ, H, D]; pools [NB+1, bs, KH, D]; bt [B, nbs] pre-clipped
+    q [B, KQ, H, D]; pools and ``layer`` as
+    :func:`paged_decode_attention_bhd`; bt [B, nbs] pre-clipped
     physical block ids; mask [B, KQ, nbs*bs] — row ``i`` carries the
     causality set of position ``pos + i`` (plus ring validity/window), so
     draft token ``i`` attends every accepted key *and* the keys scattered
@@ -243,7 +273,8 @@ def paged_verify_attention_bhd(q: jax.Array, k_pool: jax.Array,
     is the decode kernel's, and with ``KQ == 1`` the arithmetic is too.
     """
     b, kq, h, d = q.shape
-    bs, kh = k_pool.shape[1], k_pool.shape[2]
+    k_pool, v_pool, layer = _layered(k_pool, v_pool, layer)
+    bs, kh = k_pool.shape[2], k_pool.shape[3]
     assert h % kh == 0, (h, kh)
     g = h // kh
     c = bt.shape[1] * bs
@@ -253,7 +284,7 @@ def paged_verify_attention_bhd(q: jax.Array, k_pool: jax.Array,
         b, kh, kq * g, d)
     rows = jnp.broadcast_to(mask[:, :, None, :], (b, kq, g, c)).reshape(
         b, kq * g, c)
-    out = _paged_call(q4, k_pool, v_pool, bt, _blocked_mask(rows, bs),
+    out = _paged_call(q4, k_pool, v_pool, bt, layer, _blocked_mask(rows, bs),
                       softcap=softcap, interpret=interpret,
                       name="paged_verify_attention")
     return out.reshape(b, kh, kq, g, d).transpose(0, 2, 1, 3, 4).reshape(

@@ -90,24 +90,26 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "interpret"))
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                            bt: jax.Array, key_pos: jax.Array, pos: jax.Array,
+                           layer: Optional[jax.Array] = None,
                            *, window: Optional[int] = None,
                            softcap: Optional[float] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Paged decode through the block table — no gathered cache temporary.
 
     q [B,1,H,D] or [B,H,D]; k_pool/v_pool [NB+1, bs, KH, D] (last block =
-    scratch); bt [B, nbs] int32 block table (-1 = unmapped, redirected to
-    the scratch block whose keys the validity mask hides); key_pos [B, C]
-    per-ring-slot absolute positions (-1 = empty, C == nbs*bs); pos [B]
-    per-slot decode positions.
+    scratch), or stacked over layers ``[L, NB+1, bs, KH, D]`` and read in
+    place at ``layer`` (scalar int32); bt [B, nbs] int32 block table (-1 =
+    unmapped, redirected to the scratch block whose keys the validity mask
+    hides); key_pos [B, C] per-ring-slot absolute positions (-1 = empty,
+    C == nbs*bs); pos [B] per-slot decode positions.
     """
     if interpret is None:
         interpret = _on_cpu()
     q3 = q[:, 0] if q.ndim == 4 else q
     b = q3.shape[0]
     nbs = bt.shape[1]
-    scratch = k_pool.shape[0] - 1
-    assert key_pos.shape == (b, nbs * k_pool.shape[1]), \
+    scratch = k_pool.shape[-4] - 1
+    assert key_pos.shape == (b, nbs * k_pool.shape[-3]), \
         (key_pos.shape, bt.shape, k_pool.shape)
     # validity is position-driven, exactly like the contiguous decode mask
     mask = (key_pos >= 0) & (key_pos <= pos[:, None])
@@ -115,7 +117,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         mask &= key_pos > pos[:, None] - window
     bt_read = jnp.where(bt >= 0, bt, scratch).astype(jnp.int32)
     out = paged_decode_attention_bhd(q3, k_pool, v_pool, bt_read, mask,
-                                     softcap=softcap, interpret=interpret)
+                                     layer=layer, softcap=softcap,
+                                     interpret=interpret)
     if q.ndim == 4:
         return out[:, None]
     return out
@@ -124,25 +127,27 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "interpret"))
 def paged_verify_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                            bt: jax.Array, key_pos: jax.Array, pos: jax.Array,
+                           layer: Optional[jax.Array] = None,
                            *, window: Optional[int] = None,
                            softcap: Optional[float] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Speculative-verify attention: ``KQ`` draft tokens per slot, one pass.
 
-    q [B, KQ, H, D]; pools/bt/key_pos as :func:`paged_decode_attention`;
-    pos [B] is the position of the *first* fed token, so q row ``i``
-    decodes at position ``pos + i`` and its mask admits keys with
-    ``key_pos <= pos + i`` — the per-row causality that lets the drafts'
-    freshly-scattered keys be attended by later drafts only.  Rows past a
-    slot's true draft count are fully masked by construction when their
-    keys were never scattered; callers discard their outputs regardless.
+    q [B, KQ, H, D]; pools/bt/key_pos/layer as
+    :func:`paged_decode_attention`; pos [B] is the position of the *first*
+    fed token, so q row ``i`` decodes at position ``pos + i`` and its mask
+    admits keys with ``key_pos <= pos + i`` — the per-row causality that
+    lets the drafts' freshly-scattered keys be attended by later drafts
+    only.  Rows past a slot's true draft count are fully masked by
+    construction when their keys were never scattered; callers discard
+    their outputs regardless.
     """
     if interpret is None:
         interpret = _on_cpu()
     b, kq = q.shape[0], q.shape[1]
     nbs = bt.shape[1]
-    scratch = k_pool.shape[0] - 1
-    assert key_pos.shape == (b, nbs * k_pool.shape[1]), \
+    scratch = k_pool.shape[-4] - 1
+    assert key_pos.shape == (b, nbs * k_pool.shape[-3]), \
         (key_pos.shape, bt.shape, k_pool.shape)
     pos_i = pos[:, None, None] + jnp.arange(kq)[None, :, None]   # [B,KQ,1]
     mask = (key_pos[:, None, :] >= 0) & (key_pos[:, None, :] <= pos_i)
@@ -150,7 +155,8 @@ def paged_verify_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         mask &= key_pos[:, None, :] > pos_i - window
     bt_read = jnp.where(bt >= 0, bt, scratch).astype(jnp.int32)
     return paged_verify_attention_bhd(q, k_pool, v_pool, bt_read, mask,
-                                      softcap=softcap, interpret=interpret)
+                                      layer=layer, softcap=softcap,
+                                      interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))

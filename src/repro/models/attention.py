@@ -461,6 +461,7 @@ def attend_decode(params: Dict, cfg: ModelConfig, spec: BlockSpec,
 def attend_decode_paged(params: Dict, cfg: ModelConfig, spec: BlockSpec,
                         x: jax.Array, cache: Dict, impl: str = "xla",
                         write_mask: Optional[jax.Array] = None,
+                        layer: Optional[jax.Array] = None,
                         ) -> Tuple[jax.Array, Dict]:
     """One-token decode against a *paged* KV cache. x: [B, 1, d].
 
@@ -485,6 +486,12 @@ def attend_decode_paged(params: Dict, cfg: ModelConfig, spec: BlockSpec,
     ``key_pos``/``pos``, so idle slots and dead pipeline ticks can never
     touch another slot's blocks.
 
+    ``layer`` (scalar int32) says the pool leaves are stacked over layers
+    (``[L, NB+1, bs, n_kv, hd]``, the decode layer scan's carry): this
+    token is scattered into the stacked pool at ``(layer, block, offset)``
+    and the pool is read at ``layer`` in place, so no layer's pool is ever
+    sliced out or written back.  The returned pools stay stacked.
+
     ``impl`` selects how the pool is *read* (unknown values raise):
 
     - ``"pallas"`` — :func:`repro.kernels.ops.paged_decode_attention`: the
@@ -508,9 +515,10 @@ def attend_decode_paged(params: Dict, cfg: ModelConfig, spec: BlockSpec,
     else:
         pos, bt, key_pos = cache["pos"], cache["bt"], cache["key_pos"]
     c_pad = key_pos.shape[-1]
-    bsz = cache["k_pool"].shape[1]                    # tokens per block
+    bsz = cache["k_pool"].shape[-3]                   # tokens per block
     nbs = c_pad // bsz                                # this spec's table span
-    scratch = cache["k_pool"].shape[0] - 1
+    scratch = cache["k_pool"].shape[-4] - 1
+    at = () if layer is None else (layer,)            # the pools' layer
     positions = pos[:, None]                                      # [B, 1]
     q, k, v = _project_qkv(params, cfg, x, positions)
 
@@ -527,14 +535,14 @@ def attend_decode_paged(params: Dict, cfg: ModelConfig, spec: BlockSpec,
     if quant:
         k8, ks = _quantize_kv(k)
         v8, vs = _quantize_kv(v)
-        kp = cache["k_pool"].at[tgt, off].set(k8[:, 0])
-        vp = cache["v_pool"].at[tgt, off].set(v8[:, 0])
-        ksp = cache["k_scale_pool"].at[tgt, off].set(ks[:, 0])
-        vsp = cache["v_scale_pool"].at[tgt, off].set(vs[:, 0])
+        kp = cache["k_pool"].at[at + (tgt, off)].set(k8[:, 0])
+        vp = cache["v_pool"].at[at + (tgt, off)].set(v8[:, 0])
+        ksp = cache["k_scale_pool"].at[at + (tgt, off)].set(ks[:, 0])
+        vsp = cache["v_scale_pool"].at[at + (tgt, off)].set(vs[:, 0])
     else:
-        kp = cache["k_pool"].at[tgt, off].set(
+        kp = cache["k_pool"].at[at + (tgt, off)].set(
             k[:, 0].astype(cache["k_pool"].dtype))
-        vp = cache["v_pool"].at[tgt, off].set(
+        vp = cache["v_pool"].at[at + (tgt, off)].set(
             v[:, 0].astype(cache["v_pool"].dtype))
 
     new_key_pos = key_pos.at[jnp.arange(b), ring].set(pos.astype(jnp.int32))
@@ -546,7 +554,7 @@ def attend_decode_paged(params: Dict, cfg: ModelConfig, spec: BlockSpec,
     if impl == "pallas" and not quant:
         from repro.kernels import ops as kops
         out = kops.paged_decode_attention(
-            q, kp, vp, bt[:, :nbs], new_key_pos, pos,
+            q, kp, vp, bt[:, :nbs], new_key_pos, pos, layer,
             window=spec.window, softcap=cfg.attn_logit_softcap)
         out = out.reshape(b, 1, cfg.q_dim)
     else:
@@ -555,7 +563,7 @@ def attend_decode_paged(params: Dict, cfg: ModelConfig, spec: BlockSpec,
         # reference / int8 fallback: gather the slot's blocks back in ring
         # order ([B, C_pad, n_kv, hd]); unmapped entries read block 0
         # garbage, masked via key_pos == -1
-        read = jnp.clip(bt[:, :nbs], 0, None)
+        read = at + (jnp.clip(bt[:, :nbs], 0, None),)
         if quant:
             ck = _dequantize_kv(
                 kp[read].reshape(b, c_pad, cfg.n_kv_heads, -1),

@@ -127,6 +127,11 @@ def init_paged_block_cache(cfg: ModelConfig, spec: BlockSpec, batch: int,
     return out
 
 
+#: the block-pool leaves of a paged attention cache (the scale pools only
+#: with ``kv_dtype="int8"``); every other leaf is per-slot
+POOL_KEYS = ("k_pool", "v_pool", "k_scale_pool", "v_scale_pool")
+
+
 def is_paged_attn_cache(cache: Dict) -> bool:
     return isinstance(cache, dict) and "k_pool" in cache
 

@@ -24,9 +24,9 @@ from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
 from repro.models import xlstm as xlstm_mod
 from repro.models.config import BlockSpec, ModelConfig
-from repro.models.kvcache import (DEFAULT_BLOCK_SIZE, cache_logical_axes,
-                                  init_block_cache, init_paged_block_cache,
-                                  is_paged_attn_cache)
+from repro.models.kvcache import (DEFAULT_BLOCK_SIZE, POOL_KEYS,
+                                  cache_logical_axes, init_block_cache,
+                                  init_paged_block_cache, is_paged_attn_cache)
 from repro.models.layers import (ParamBuilder, apply_mlp, apply_norm,
                                  embed_tokens, init_embedding, init_mlp,
                                  init_norm, lm_logits, sinusoidal_embedding)
@@ -212,9 +212,12 @@ def _apply_block(cfg: ModelConfig, spec: BlockSpec, params: Dict,
                  write_mask: Optional[jax.Array] = None,
                  seq_valid: Optional[jax.Array] = None,
                  verify_lens: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None,
                  ) -> Tuple[jax.Array, Optional[Dict], jax.Array]:
     """Returns (x_out, new_cache, aux_loss).  ``write_mask`` gates paged
-    KV-pool writes (idle slots / dead pipeline ticks scatter to scratch).
+    KV-pool writes (idle slots / dead pipeline ticks scatter to scratch);
+    ``layer`` marks paged pools stacked over layers, updated at that layer
+    in place (:func:`repro.models.attention.attend_decode_paged`).
 
     ``seq_valid`` ([B, S], masked prefill) marks pad positions invalid:
     attention masks them via the negative per-row ``positions``, recurrent
@@ -245,7 +248,7 @@ def _apply_block(cfg: ModelConfig, spec: BlockSpec, params: Dict,
         elif is_paged_attn_cache(cache):
             mix, new_cache = attn.attend_decode_paged(
                 params["mixer"], cfg, spec, h, cache, impl,
-                write_mask=write_mask)
+                write_mask=write_mask, layer=layer)
         else:
             mix, new_cache = attn.attend_decode(params["mixer"], cfg, spec, h,
                                                 cache, impl)
@@ -404,6 +407,14 @@ def decode_step(cfg: ModelConfig, params: PyTree, inputs: jax.Array,
     slots' pool writes.  ``impl="pallas"`` dispatches the Pallas decode
     kernels on both layouts (the paged kernel reads pool blocks through
     the table — no per-step gather); unknown impls raise.
+
+    The stacked block pools of paged attention entries ride in the layer
+    scan's carry, not its xs/ys: each layer scatters its token into the
+    stacked pool at ``(layer, block, offset)`` and reads the pool at
+    ``layer`` in place, so a step moves one token per slot per layer of
+    pool memory, not the whole pool (with the caches donated, the pool's
+    output buffer is its input's).  Per-slot leaves and every other cache
+    kind stay in xs/ys.
     """
     if inputs.ndim == 1 and jnp.issubdtype(inputs.dtype, jnp.integer):
         inputs2 = inputs[:, None]
@@ -415,19 +426,39 @@ def decode_step(cfg: ModelConfig, params: PyTree, inputs: jax.Array,
     new_caches: Dict[str, Any] = {}
 
     if cfg.n_full_periods > 0:
-        def body(x_c, per_period):
-            p_params, p_caches = per_period
+        pools, per_layer = {}, {}
+        for name, entry in caches["stack"].items():
+            if is_paged_attn_cache(entry):
+                pools[name] = {k: v for k, v in entry.items()
+                               if k in POOL_KEYS}
+            per_layer[name] = {k: v for k, v in entry.items()
+                               if k not in pools.get(name, ())}
+
+        def body(carry, per_period):
+            x_c, pools_c = carry
+            p_params, p_caches, layer = per_period
             new_p = {}
             for p, spec in enumerate(cfg.pattern):
-                x_c, nc, _ = _apply_block(cfg, spec, p_params[f"p{p}"], x_c,
-                                          positions, "decode",
-                                          p_caches[f"p{p}"], impl,
-                                          write_mask=write_mask)
-                new_p[f"p{p}"] = nc
-            return x_c, new_p
+                name = f"p{p}"
+                own = pools_c.get(name)
+                cache = p_caches[name] if own is None \
+                    else {**p_caches[name], **own}
+                x_c, nc, _ = _apply_block(
+                    cfg, spec, p_params[name], x_c, positions, "decode",
+                    cache, impl, write_mask=write_mask,
+                    layer=None if own is None else layer)
+                if own is not None:
+                    pools_c = {**pools_c, name: {k: nc[k] for k in own}}
+                    nc = {k: v for k, v in nc.items() if k not in own}
+                new_p[name] = nc
+            return (x_c, pools_c), new_p
 
-        x, new_caches["stack"] = jax.lax.scan(
-            body, x, (params["stack"], caches["stack"]))
+        (x, pools), per_layer = jax.lax.scan(
+            body, (x, pools),
+            (params["stack"], per_layer,
+             jnp.arange(cfg.n_full_periods, dtype=jnp.int32)))
+        new_caches["stack"] = {name: {**entry, **pools.get(name, {})}
+                               for name, entry in per_layer.items()}
 
     if cfg.tail:
         new_tail = {}

@@ -354,6 +354,37 @@ def test_paged_verify_ring_wraparound_window():
                                rtol=3e-5, atol=3e-5)
 
 
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_paged_kernel_reads_the_given_layer_of_a_stacked_pool(kind):
+    """Pools stacked over layers ``[L, NB+1, bs, KH, D]`` and read at
+    ``layer`` through the kv index map give bit for bit what the kernel
+    gives on ``pool[layer]``, for every layer: each layer (its scratch
+    block too) holds other values, and row 0's last table entry is
+    unmapped, so it reads the scratch block."""
+    b, h, kh, d, bs, nbs, kq, n_layers = 2, 4, 2, 32, 16, 3, 3, 3
+    _, _, bt, key_pos, pos, kr = _paged_case(
+        b, kh, d, bs, nbs, num_blocks=b * nbs + 2, lens=(40, 25), seed=33)
+    ks = jax.random.split(K(34), 2)
+    pools = (b * nbs + 3, bs, kh, d)
+    k_stack = jax.random.normal(ks[0], (n_layers,) + pools)
+    v_stack = jax.random.normal(ks[1], (n_layers,) + pools)
+    if kind == "decode":
+        q = jax.random.normal(kr, (b, h, d))
+        call = ops.paged_decode_attention
+    else:
+        q = jax.random.normal(kr, (b, kq, h, d))
+        call = ops.paged_verify_attention
+    outs = []
+    for layer in range(n_layers):
+        got = call(q, k_stack, v_stack, bt, key_pos, pos,
+                   jnp.asarray(layer, jnp.int32), interpret=True)
+        want = call(q, k_stack[layer], v_stack[layer], bt, key_pos, pos,
+                    interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        outs.append(np.asarray(got))
+    assert not np.array_equal(outs[0], outs[1])     # the layers differ
+
+
 # ---- model-level: attend_decode_paged dispatch (per-slot vs shared,
 # ---- write_mask scratch isolation, impl contract)
 
@@ -449,6 +480,146 @@ def test_attend_decode_paged_unknown_impl_raises():
         A.attend_decode_paged(params, cfg, spec, x, cache, "cuda")
     with pytest.raises(ValueError, match="unknown decode impl"):
         A.attend_decode(params, cfg, spec, x, cache, "cuda")
+
+
+# ---- model-level: decode_step over a stacked pool carried through the scan
+
+def _stacked_paged_state(cfg, n_slots, max_len, pos, seed):
+    """Paged caches whose pools hold random history: slot ``s`` owns
+    blocks ``s*nbs .. s*nbs+nbs-1`` and has seen positions ``0..pos[s]-1``
+    (ring-wrapped in windowed layers), and the contiguous caches that hold
+    the same keys and values."""
+    from repro.models import transformer as T
+    from repro.models.kvcache import max_ctx_blocks
+    nbs = max_ctx_blocks(cfg, max_len, 16)
+    paged = T.init_paged_caches(cfg, n_slots, max_len, n_slots * nbs, 16,
+                                jnp.float32)
+    dense = T.init_caches(cfg, n_slots, max_len, jnp.float32)
+    keys = iter(jax.random.split(K(seed), 16))
+    bt = np.arange(n_slots * nbs, dtype=np.int32).reshape(n_slots, nbs)
+
+    def fill(pe, de):
+        c_pad, c = pe["key_pos"].shape[-1], de["key_pos"].shape[-1]
+        key_pos = np.full((n_slots, c_pad), -1, np.int32)
+        for s, p in enumerate(pos):
+            for t in range(p):
+                key_pos[s, t % c] = t
+        lead = pe["k_pool"].shape[:-4]
+        pe, de = dict(pe), dict(de)
+        for name in ("k", "v"):
+            pool = jax.random.normal(next(keys), pe[f"{name}_pool"].shape)
+            pe[f"{name}_pool"] = pool
+            rows = pool[..., bt[:, :c_pad // 16], :, :, :]
+            de[name] = rows.reshape(lead + (n_slots, c_pad) + rows.shape[-2:]
+                                    )[..., :c, :, :]
+        pe["bt"] = jnp.broadcast_to(jnp.asarray(bt), pe["bt"].shape)
+        pe["key_pos"] = jnp.broadcast_to(jnp.asarray(key_pos),
+                                         pe["key_pos"].shape)
+        de["key_pos"] = jnp.broadcast_to(jnp.asarray(key_pos[:, :c]),
+                                         de["key_pos"].shape)
+        for e in (pe, de):
+            e["pos"] = jnp.broadcast_to(jnp.asarray(pos, jnp.int32),
+                                        e["pos"].shape)
+        return pe, de
+
+    for group in ("stack", "tail"):
+        for name in paged[group]:
+            paged[group][name], dense[group][name] = fill(
+                paged[group][name], dense[group][name])
+    return paged, dense
+
+
+def _layer_entries(caches):
+    """(stack entry, layer) or (tail entry, None) for every attention layer."""
+    for entry in caches["stack"].values():
+        for layer in range(entry["pos"].shape[0]):
+            yield entry, layer
+    for entry in caches["tail"].values():
+        yield entry, None
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_stacked_paged_decode_step_writes_each_token_into_its_own_layer(impl):
+    """Two full periods of a (local, global) pattern plus a tail layer,
+    three slots, four steps, slot 1 write-masked.  Each step writes each
+    live slot's token at ``(layer, block, offset)`` of every layer's pool
+    with that layer's own key and value (the contiguous layout's, decoded
+    alongside), changes nothing else in any pool but the scratch block,
+    freezes the masked slot, and gives the contiguous layout's logits."""
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    cfg = get_config("gemma2-2b").reduced(n_layers=5)
+    assert cfg.n_full_periods == 2 and len(cfg.pattern) == 2 and cfg.tail
+    n_slots, max_len, pos = 3, 64, [20, 9, 33]
+    params, _ = T.init_params(cfg, K(40))
+    paged, dense = _stacked_paged_state(cfg, n_slots, max_len, pos, seed=41)
+    wm = jnp.array([True, False, True])
+    live = [0, 2]
+    step_paged = jax.jit(lambda t, c: T.decode_step(
+        cfg, params, t, c, impl=impl, write_mask=wm))
+    step_dense = jax.jit(lambda t, c: T.decode_step(cfg, params, t, c,
+                                                    impl=impl))
+    tokens = jnp.array([3, 5, 7], jnp.int32)
+    for _ in range(4):
+        logits, new = step_paged(tokens, paged)
+        dlogits, dnew = step_dense(tokens, dense)
+        np.testing.assert_allclose(np.asarray(logits)[live],
+                                   np.asarray(dlogits)[live],
+                                   rtol=1e-5, atol=1e-5)
+        for (old_e, layer), (new_e, _), (dense_e, _) in zip(
+                _layer_entries(paged), _layer_entries(new),
+                _layer_entries(dnew)):
+            sel = (lambda a: a[layer]) if layer is not None else (lambda a: a)
+            c_pad, c = sel(old_e["key_pos"]).shape[-1], \
+                sel(dense_e["key_pos"]).shape[-1]
+            p_old = np.asarray(sel(old_e["pos"]))
+            bt = np.asarray(sel(old_e["bt"]))
+            for name in ("k", "v"):
+                before = np.array(sel(old_e[f"{name}_pool"]))
+                after = np.asarray(sel(new_e[f"{name}_pool"]))
+                for s in live:
+                    ring = p_old[s] % c_pad
+                    blk, off = bt[s, ring // 16], ring % 16
+                    np.testing.assert_allclose(
+                        after[blk, off],
+                        np.asarray(sel(dense_e[name]))[s, p_old[s] % c],
+                        rtol=1e-5, atol=1e-5)
+                    before[blk, off] = after[blk, off]
+                # nothing else moved: not the masked slot's blocks, not
+                # another layer's slots (the scratch block may)
+                np.testing.assert_array_equal(after[:-1], before[:-1])
+            np.testing.assert_array_equal(
+                np.asarray(sel(new_e["pos"])), p_old + np.array([1, 0, 1]))
+            np.testing.assert_array_equal(
+                np.asarray(sel(new_e["key_pos"]))[1],
+                np.asarray(sel(old_e["key_pos"]))[1])
+        # the contiguous layout has no write mask: its slot 1 runs on
+        # unread, and the rows do not interact
+        paged, dense = new, dnew
+        tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def test_stacked_paged_decode_greedy_tokens_match_xla():
+    """Greedy decoding through the carried pool: the Pallas kernel reading
+    each layer through its index map gives the tokens of the ``xla``
+    gather path, step for step, with a write-masked slot."""
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    cfg = get_config("gemma2-2b").reduced(n_layers=5)
+    params, _ = T.init_params(cfg, K(42))
+    wm = jnp.array([True, True, False])
+    streams = {}
+    for impl in ("xla", "pallas"):
+        caches, _ = _stacked_paged_state(cfg, 3, 64, [20, 9, 33], seed=43)
+        step = jax.jit(lambda t, c, impl=impl: T.decode_step(
+            cfg, params, t, c, impl=impl, write_mask=wm))
+        tokens, out = jnp.array([3, 5, 7], jnp.int32), []
+        for _ in range(6):
+            logits, caches = step(tokens, caches)
+            tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            out.append(np.asarray(tokens)[:2])
+        streams[impl] = np.stack(out)
+    np.testing.assert_array_equal(streams["pallas"], streams["xla"])
 
 
 # --------------------------------------------------------------------------- #

@@ -9,6 +9,7 @@ runs — the compiler only has to accept each kernel and emit a
 only the worker given this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -114,3 +115,50 @@ def test_paged_decode_kernel_keeps_its_name(shape):
              if "custom-call(" in line and "tpu_custom_call" in line]
     assert calls
     assert all(c.startswith("paged_decode_attention") for c in calls), calls
+
+
+def _pool_shuffles(text, pool_shapes):
+    """(name, opcode, shape) of every copy, dynamic-slice or
+    dynamic-update-slice — bare or fused (a fusion named after it) — whose
+    result has one of ``pool_shapes`` (dims as ``"a,b,..."``)."""
+    inst = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\w+\[([\d,]*)\]"
+                      r"(?:\{[^}]*\})?\s+([\w\-]+)\(")
+    moves = ("copy", "dynamic-slice", "dynamic-update-slice")
+    found = []
+    for line in text.splitlines():
+        m = inst.match(line)
+        if not m or m.group(2) not in pool_shapes:
+            continue
+        name, dims, op = m.groups()
+        if op in moves or (op == "fusion" and any(w in name for w in moves)):
+            found.append((name, op, dims))
+    return found
+
+
+def test_paged_decode_step_keeps_the_pool_in_place(shape, monkeypatch):
+    """``decode_step`` over a stacked float32 paged pool at qwen3-0.6b's
+    widths and depth, caches donated as the serving path donates them: the
+    compiled program updates the pool in place.  No layer's pool
+    ``[NB+1, 16, 8, 128]`` is sliced out or written back, and the stacked
+    pool ``[28, NB+1, 16, 8, 128]`` is never copied; the kernel still runs
+    under its own name."""
+    from repro.models import transformer as T
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+    slots, nbs = 5, 32
+    nb = slots * nbs
+    params = jax.eval_shape(
+        lambda: T.init_params(CFG, jax.random.PRNGKey(0))[0])
+    caches = jax.eval_shape(lambda: T.init_paged_caches(
+        CFG, slots, nbs * BS, nb, BS, jnp.float32))
+    params, caches = jax.tree.map(lambda a: shape(a.shape, a.dtype),
+                                  (params, caches))
+
+    def decode_step(params, tokens, caches, write_mask):
+        return T.decode_step(CFG, params, tokens, caches, impl="pallas",
+                             write_mask=write_mask)
+    text = jax.jit(decode_step, donate_argnums=(2,)).lower(
+        params, shape((slots,), jnp.int32), caches,
+        shape((slots,), jnp.bool_)).compile().as_text()
+    assert re.search(r"paged_decode_attention[.\d]* = ", text)
+    layer = f"{nb + 1},{BS},{KH},{D}"
+    assert _pool_shuffles(text, {layer, f"{CFG.n_layers},{layer}"}) == []
