@@ -30,6 +30,10 @@ Two cache layouts, selectable via ``cache_layout``:
   length*, not ``max_len``), then scatters the wave's ring caches into the
   pool by absolute position.
 
+On both layouts each request of an admission wave is prefilled as its own
+one-row program, so a wave costs the rows it holds and every width compiles
+one prefill shape and one scatter shape.
+
 Both layouts run *masked* prefill: ``prefill(slots, prompts, prompt_lens)``
 left-pads to the bucket but masks pads out of attention and never writes
 them as valid cache keys (``models/transformer.py::forward``), so a slot's
@@ -55,7 +59,6 @@ from repro.runtime.prefix_cache import PrefixCache
 from repro.sharding.rules import use_mesh
 
 PyTree = Any
-
 
 def _flat_with_axes(caches: PyTree, axes: PyTree):
     """Zip cache leaves with their logical-axis tuples from cache_axes."""
@@ -593,64 +596,55 @@ class TensorBackend(InferenceBackend):
                 prompt_lens: Optional[Sequence[int]] = None,
                 ) -> List[SlotEvent]:
         prompts = np.atleast_2d(np.asarray(prompts, np.int32))
-        k = prompts.shape[0]
+        k, width = prompts.shape
         assert len(slots) == k
-        lens = np.full(k, prompts.shape[1], np.int32) if prompt_lens is None \
+        lens = np.full(k, width, np.int32) if prompt_lens is None \
             else np.asarray(prompt_lens, np.int32)
         assert lens.shape == (k,) and np.all(lens >= 1) \
-            and np.all(lens <= prompts.shape[1]), (lens, prompts.shape)
-        with obs.span("repro.backend.prefill", rows=k,
-                      width=prompts.shape[1]):
+            and np.all(lens <= width), (lens, prompts.shape)
+        with obs.span("repro.backend.prefill", rows=k, width=width):
             if self._paged_exec:
                 # atomic: on exhaustion nothing mutates and the scheduler can
                 # retry the wave after preempting.  Blocks cover each slot's
                 # TRUE length — pads are masked and never become cache keys.
                 with obs.span("repro.backend.pager"):
                     self.pager.realloc_wave(slots, lens)
-            # pad the wave to the full slot width by repeating the first
-            # entry (duplicate scatter indices write identical values), so
-            # prefill and scatter compile once instead of per admission-wave
-            # size
-            pad = self.n_slots - k
-            prompts_p = np.concatenate(
-                [prompts, np.repeat(prompts[:1], pad, axis=0)]) if pad \
-                else prompts
-            lens_p = np.concatenate([lens, np.repeat(lens[:1], pad)]) \
-                if pad else lens
-            slots_p = list(slots) + [slots[0]] * pad
+            # one program per request: the wave computes only the rows it
+            # holds, and each width keeps one prefill and one scatter shape
             with obs.span("repro.backend.dispatch"):
-                idx = jnp.asarray(slots_p, jnp.int32)
+                lasts = [self._prefill_row(s, prompts[i:i + 1], lens[i:i + 1])
+                         for i, s in enumerate(slots)]
                 if self._paged_exec:
-                    # dense scratch caches sized by the bucketed prompt
-                    # length (not max_len): transient prefill workspace stays
-                    # proportional to the wave, the pool holds the
-                    # persistent state
-                    fresh = T.init_caches(self.cfg, self.n_slots,
-                                          prompts.shape[1], self.cache_dtype)
-                    bt_rows = jnp.asarray(
-                        self.pager.table[np.asarray(slots_p)])
-                    with use_mesh(self.mesh):
-                        logits, new_caches, _ = self._prefill_fn(
-                            self.params, jnp.asarray(prompts_p), caches=fresh,
-                            prompt_lens=jnp.asarray(lens_p))
-                        self.caches = self._scatter_fn(
-                            self.caches, new_caches, idx, bt_rows)
                     for s, n in zip(slots, lens):
                         self._pos[s] = int(n)
                         self._active[s] = True
-                else:
-                    fresh = T.init_caches(self.cfg, self.n_slots,
-                                          self.max_len, self.cache_dtype)
-                    with use_mesh(self.mesh):
-                        logits, new_caches, _ = self._prefill_fn(
-                            self.params, jnp.asarray(prompts_p), caches=fresh,
-                            prompt_lens=jnp.asarray(lens_p))
-                        self.caches = self._scatter_fn(self.caches,
-                                                       new_caches, idx)
             with obs.span("repro.backend.fetch"):
-                last = np.asarray(logits[:, -1], np.float32)
+                last = np.concatenate(jax.device_get(lasts)).astype(np.float32)
             return [SlotEvent(slot=s, logits=last[i])
                     for i, s in enumerate(slots)]
+
+    def _prefill_row(self, slot: int, prompt: np.ndarray,
+                     lens: np.ndarray) -> jax.Array:
+        """Prefill one ``[1, S]`` prompt, scatter its caches into ``slot``'s
+        storage, and return its last-position logits ``[1, V]``, still on
+        the device (the whole ``[1, S, V]`` logits are dropped)."""
+        idx = jnp.asarray([slot], jnp.int32)
+        if self._paged_exec:
+            # dense scratch caches sized by the bucketed prompt length (not
+            # max_len): transient prefill workspace stays proportional to
+            # the prompt, the pool holds the persistent state
+            length = prompt.shape[1]
+            tables = (jnp.asarray(self.pager.table[[slot]]),)
+        else:
+            length, tables = self.max_len, ()
+        fresh = T.init_caches(self.cfg, 1, length, self.cache_dtype)
+        with use_mesh(self.mesh):
+            logits, new_caches, _ = self._prefill_fn(
+                self.params, jnp.asarray(prompt), caches=fresh,
+                prompt_lens=jnp.asarray(lens))
+            self.caches = self._scatter_fn(self.caches, new_caches, idx,
+                                           *tables)
+        return logits[:, -1]
 
     def decode_step(self, feeds: Dict[int, int]) -> List[SlotEvent]:
         if not feeds:

@@ -90,6 +90,30 @@ def test_spans_nest_in_a_profiler_trace(tmp_path):
     assert [s[3]["step"] for s in steps] == list(range(len(steps)))
 
 
+def test_prefill_span_carries_rows_and_width(tmp_path):
+    """Each prefill call's span names its wave's rows and width, for a
+    lone wide row and for short waves of 1..n_slots rows."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    from repro.runtime import TensorBackend
+    n_slots, wide = 3, 256
+    cfg = get_config("qwen3-0.6b").reduced(n_layers=2)
+    params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
+    b = TensorBackend(cfg, params, n_slots=n_slots, max_len=wide + 16,
+                      cache_layout="paged")
+    waves = [(1, wide)] + [(k, 8) for k in range(1, n_slots + 1)]
+    with jax.profiler.trace(str(tmp_path)):
+        for k, w in waves:
+            b.prefill(list(range(k)), np.ones((k, w), np.int32), [w] * k)
+            for s in range(k):
+                b.free_slot(s)
+    spans = sorted((s for s in _host_spans(tmp_path)
+                    if s[0] == "repro.backend.prefill"), key=lambda s: s[1])
+    assert [(s[3]["rows"], s[3]["width"]) for s in spans] == waves
+
+
 def test_programs_have_fixed_names():
     import jax
     import jax.numpy as jnp

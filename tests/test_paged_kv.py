@@ -265,3 +265,144 @@ def test_key_pos_masked_tail_when_cache_len_unaligned():
     [a] = contig.generate([prompt], sp)
     [b] = paged.generate([prompt], sp)
     assert a.tokens == b.tokens
+
+
+# --------------------------------------------------------------------------- #
+# prefill: one one-row program per request of a wave
+# --------------------------------------------------------------------------- #
+
+N_SLOTS = 5
+WIDE = 256
+WAVE_MAX_LEN = WIDE + 16
+
+
+@pytest.fixture(scope="module")
+def wave_model():
+    import jax
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    cfg = get_config("qwen3-0.6b").reduced(n_layers=2)
+    params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
+    return cfg, params
+
+
+def _wave_backend(wave_model, layout, num_blocks=None):
+    from repro.runtime import TensorBackend
+    cfg, params = wave_model
+    return TensorBackend(cfg, params, n_slots=N_SLOTS, max_len=WAVE_MAX_LEN,
+                         cache_layout=layout, num_blocks=num_blocks)
+
+
+def _wave(cfg, k, width, seed=0):
+    """k left-padded prompts of distinct true lengths in a ``width`` bucket."""
+    rng = np.random.default_rng(seed)
+    lens = np.array([width - width // 8 * i for i in range(k)], np.int32)
+    prompts = np.zeros((k, width), np.int32)
+    for i, n in enumerate(lens):
+        prompts[i, width - n:] = rng.integers(1, cfg.vocab_size, n)
+    return prompts, lens
+
+
+def _slot_state(be, slot):
+    """The slot's cache contents: its pool blocks (paged) or storage row."""
+    import jax
+    if be.pager is None:
+        return [np.asarray(x[slot]) for x in jax.tree.leaves(be.caches)]
+    blocks = be.pager.table[slot, :int(be.pager.n_alloc[slot])]
+    return [np.asarray(c[name][:, blocks if name.endswith("_pool") else slot])
+            for c in be.caches["stack"].values()
+            for name in ("k_pool", "v_pool", "key_pos", "pos")]
+
+
+def _spy(be):
+    """Record the rows of every prefill and scatter program ``be`` runs."""
+    rows = {"prefill": [], "scatter": []}
+    prefill, scatter = be._prefill_fn, be._scatter_fn
+
+    def prefill_spy(params, tokens, **kw):
+        rows["prefill"].append(tokens.shape[0])
+        return prefill(params, tokens, **kw)
+
+    def scatter_spy(storage, new, idx, *rest):
+        rows["scatter"].append(idx.shape[0])
+        return scatter(storage, new, idx, *rest)
+    be._prefill_fn, be._scatter_fn = prefill_spy, scatter_spy
+    return rows, prefill, scatter
+
+
+@pytest.mark.parametrize("k", range(1, N_SLOTS + 1))
+@pytest.mark.parametrize("layout", ["paged", "contiguous"])
+def test_wide_wave_matches_each_prompt_alone(wave_model, layout, k):
+    """A k-row wave at a wide width gives each request the last-position
+    logits, cache contents and greedy tokens of its prompt prefilled
+    alone, with one one-row program per request."""
+    cfg, _ = wave_model
+    prompts, lens = _wave(cfg, k, WIDE, seed=k)
+    slots = list(range(N_SLOTS - k, N_SLOTS))
+    wave, alone = (_wave_backend(wave_model, layout) for _ in range(2))
+    rows, _, _ = _spy(wave)
+    got = wave.prefill(slots, prompts, lens)
+    assert rows == {"prefill": [1] * k, "scatter": [1] * k}
+    want = [alone.prefill([s], prompts[i:i + 1], lens[i:i + 1])[0]
+            for i, s in enumerate(slots)]
+    for g, w in zip(got, want):
+        assert g.slot == w.slot
+        np.testing.assert_allclose(g.logits, w.logits, rtol=1e-5, atol=1e-5)
+        assert int(np.argmax(g.logits)) == int(np.argmax(w.logits))
+    for s in slots:
+        for a, b in zip(_slot_state(wave, s), _slot_state(alone, s)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+    feeds = {e.slot: int(np.argmax(e.logits)) for e in want}
+    for _ in range(2):
+        g = wave.decode_step(feeds)
+        w = alone.decode_step(feeds)
+        assert [int(np.argmax(e.logits)) for e in g] == \
+            [int(np.argmax(e.logits)) for e in w]
+        feeds = {e.slot: int(np.argmax(e.logits)) for e in w}
+
+
+@pytest.mark.parametrize("width", [WIDE, 8])
+@pytest.mark.parametrize("layout", ["paged", "contiguous"])
+def test_waves_add_no_program_shape(wave_model, layout, width):
+    """After one one-row prefill per width (the benchmark's warm-up), waves
+    of 2..n_slots rows compile nothing new: every wave, wide or narrow,
+    runs one-row programs."""
+    cfg, _ = wave_model
+    be = _wave_backend(wave_model, layout)
+    rows, prefill, scatter = _spy(be)
+    be.prefill([0], *_wave(cfg, 1, width))
+    be.free_slot(0)
+    compiled = (prefill._cache_size(), scatter._cache_size())
+    assert compiled == (1, 1)
+    for k in range(2, N_SLOTS + 1):
+        be.prefill(list(range(k)), *_wave(cfg, k, width))
+        for s in range(k):
+            be.free_slot(s)
+    assert (prefill._cache_size(), scatter._cache_size()) == compiled
+    n_rows = sum(range(1, N_SLOTS + 1))      # the 1-row warm-up, then 2..5
+    assert rows["prefill"] == rows["scatter"] == [1] * n_rows
+
+
+@pytest.mark.parametrize("k", range(1, N_SLOTS + 1))
+def test_pool_exhausted_wide_wave_mutates_nothing(wave_model, k):
+    """A k-row wide wave the pool cannot hold raises PoolExhausted before
+    any program runs: the pager and the pool are as they were."""
+    import jax
+    cfg, _ = wave_model
+    blocks = WIDE // 16
+    resident = k < N_SLOTS
+    be = _wave_backend(wave_model, "paged",
+                       num_blocks=blocks * k - 1 + int(resident))
+    if resident:                    # one short request already decoding
+        be.prefill([N_SLOTS - 1], np.arange(1, 9, dtype=np.int32)[None], [8])
+    pager = (be.pager.table.copy(), be.pager.n_alloc.copy(),
+             be.pager.free_blocks)
+    pool = [np.asarray(x) for x in jax.tree.leaves(be.caches)]
+    prompts, _ = _wave(cfg, k, WIDE)
+    with pytest.raises(PoolExhausted):
+        be.prefill(list(range(k)), prompts, [WIDE] * k)
+    np.testing.assert_array_equal(be.pager.table, pager[0])
+    np.testing.assert_array_equal(be.pager.n_alloc, pager[1])
+    assert be.pager.free_blocks == pager[2]
+    for a, b in zip(jax.tree.leaves(be.caches), pool):
+        np.testing.assert_array_equal(np.asarray(a), b)
